@@ -15,32 +15,6 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BASELINE_PATH = os.path.join(REPO_ROOT, "BENCH_throughput.json")
 
 
-def enable_persistent_cache():
-    """Opt-in JAX persistent compilation cache (``REPRO_JIT_CACHE_DIR``).
-
-    The pipeline/throughput benchmarks are compile-heavy (a dozen
-    shard_map scan programs); with the env knob set, bench-smoke and
-    repeat local runs stop re-paying those compiles. Returns the cache
-    dir when enabled, None otherwise. Safe on jax versions without the
-    config knobs (silently disabled).
-    """
-    cache_dir = os.environ.get("REPRO_JIT_CACHE_DIR")
-    if not cache_dir:
-        return None
-    os.makedirs(cache_dir, exist_ok=True)
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # CPU-backend compiles are small and fast individually - cache
-        # everything rather than only >1s entries
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # noqa: BLE001 - older jax: knob names differ; skip
-        return None
-    return cache_dir
-
-
 def record_baseline(entries: dict, *, force: bool = False,
                     path: str | None = None) -> list:
     """Merge NEW metric keys into a write-once baseline JSON.

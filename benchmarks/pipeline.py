@@ -87,10 +87,6 @@ import json, os, time
 import jax, jax.numpy as jnp, numpy as np
 from dataclasses import replace
 
-from benchmarks.common import enable_persistent_cache
-
-enable_persistent_cache()  # REPRO_JIT_CACHE_DIR rides the environment
-
 from repro.configs import get_config
 from repro.models import init_params
 from repro.core.pipeline import (
@@ -172,10 +168,6 @@ _TRANSPORT_SNIPPET = """
 import json, os, time
 import jax, jax.numpy as jnp, numpy as np
 from dataclasses import replace
-
-from benchmarks.common import enable_persistent_cache
-
-enable_persistent_cache()
 
 from repro.configs import get_config
 from repro.models import init_params

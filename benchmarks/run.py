@@ -16,7 +16,7 @@ import argparse
 import sys
 import time
 
-from benchmarks.common import BenchConfig, emit_csv_row, enable_persistent_cache
+from benchmarks.common import BenchConfig, emit_csv_row
 
 ALL = [
     "fig3_convergence",
@@ -74,9 +74,6 @@ def main(argv=None) -> None:
 
     if args.full and args.smoke:
         ap.error("--full and --smoke are mutually exclusive")
-    cache_dir = enable_persistent_cache()  # REPRO_JIT_CACHE_DIR opt-in
-    if cache_dir:
-        print(f"# jit cache: {cache_dir}", flush=True)
     bench = BenchConfig(quick=not args.full, smoke=args.smoke,
                         leakage=args.leakage)
     names = ALL if not args.only else select(ALL, args.only)
@@ -99,4 +96,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    print(f"# compile cache: {enable_compile_cache()}", flush=True)
     main()

@@ -59,10 +59,6 @@ _SERVE_SNIPPET = """
 import json, os
 import numpy as np
 
-from benchmarks.common import enable_persistent_cache
-
-enable_persistent_cache()  # REPRO_JIT_CACHE_DIR rides the environment
-
 from repro.serving import ServeConfig, ServingService, poisson_trace
 from repro.serving.engine import init_engine_state
 from repro.launch.serve import run_static
@@ -109,10 +105,6 @@ print("RESULT " + json.dumps({
 _FAULT_SNIPPET = """
 import json, os
 import numpy as np
-
-from benchmarks.common import enable_persistent_cache
-
-enable_persistent_cache()
 
 from repro.core import faults as F
 from repro.serving import ServeConfig, ServingService, poisson_trace

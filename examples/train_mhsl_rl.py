@@ -6,14 +6,15 @@
 3. EXECUTE that plan as real pipeline-parallel training of the (reduced)
    model over multiple JAX devices, multi-hop activations via ppermute.
 
+On the CPU, give the pipeline stage [3/3] four host devices:
+
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
     PYTHONPATH=src python examples/train_mhsl_rl.py --arch qwen2.5-3b
+
+On an accelerator the stages take the devices JAX finds (one per stage,
+at most ``--stages``).
 """
 import argparse
-import os
-
-if "--xla-devices" in os.sys.argv or True:
-    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
 import jax
 import jax.numpy as jnp
@@ -21,36 +22,10 @@ import numpy as np
 from dataclasses import replace
 
 from repro.api import (MHSLEnv, NetworkConfig, PipelineConfig, SACConfig,
-                       adamw, flat_dim, get_config, init_params,
-                       make_stage_mesh, onehot, pipeline_step_fn,
-                       select_action, train_sac, transformer_profile)
+                       adamw, get_config, init_params, make_stage_mesh,
+                       pipeline_step_fn, rollout_plan, train_sac,
+                       transformer_profile)
 from repro.optim.optimizers import apply_updates
-
-
-def rollout_plan(env, params, cfg, seed=7):
-    key = jax.random.PRNGKey(seed)
-    st = env.reset(jax.random.PRNGKey(0))
-    pair_dim = env.obs_dim + flat_dim(env.action_dims)
-    hist = jnp.zeros((cfg.hist_len, pair_dim))
-    hmask = jnp.zeros((cfg.hist_len,))
-    leaked = 0.0
-    for t in range(env.episode_len):
-        key, ka, ks = jax.random.split(key, 3)
-        obs = env.observe(st)
-        masks = env.action_masks(st)
-        a = select_action(params, ka, obs, hist, hmask, masks, env.action_dims, cfg)
-        pair = jnp.concatenate([obs, onehot(a, env.action_dims)])
-        hist = jnp.roll(hist, -1, axis=0).at[-1].set(pair)
-        hmask = jnp.roll(hmask, -1).at[-1].set(1.0)
-        st, r, done, info = env.step(st, a, ks)
-        leaked += float(info["leak"])
-    return (
-        tuple(int(b) for b in np.asarray(st.boundaries)),
-        tuple(int(d) for d in np.asarray(st.stage_dev)),
-        leaked,
-        float(st.t_r),
-        float(st.e_r),
-    )
 
 
 def main():
@@ -142,4 +117,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -28,7 +28,7 @@ from repro.attack import (AttackConfig, capture_weight,
 from repro.checkpoint import load_pytree, save_pytree
 from repro.configs import get_config
 from repro.core.agents.action_space import flat_dim, onehot
-from repro.core.agents.loops import train_sac
+from repro.core.agents.loops import rollout_plan, train_sac
 from repro.core.agents.sac import SACConfig, select_action
 from repro.core.channel import NetworkConfig
 from repro.core.env import MHSLEnv
@@ -92,6 +92,7 @@ __all__ = [
     "pipeline_step_fn",
     "plan_hop_geometry",
     "reference_schedule",
+    "rollout_plan",
     "sample_fault_schedule",
     "save_pytree",
     "score_plans",

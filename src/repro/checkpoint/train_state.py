@@ -19,21 +19,20 @@ trajectory is bit-identical to an uninterrupted one (pinned by
 Restore is sharding-aware: pass ``shardings`` (or a ``like`` tree of
 already-placed arrays) and every leaf is ``device_put`` onto its mesh
 placement, so long sharded-population runs resume straight onto the mesh.
+
+JAX is imported only by the functions that touch arrays: the chaos
+harness's parent polls :func:`latest_checkpoint_step` and must stay off
+the accelerator its children use.
 """
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import re
 from typing import Any, Dict, Optional, Tuple
 
-import jax
-
-import hashlib
-
 import numpy as np
-
-from repro.checkpoint.store import load_pytree, save_pytree
 
 _STEP_RE = re.compile(r"^step_(\d{8})\.npz$")
 
@@ -44,6 +43,8 @@ def pytree_fingerprint(tree: Any) -> Optional[str]:
     None out (no scenario override)."""
     if tree is None:
         return None
+    import jax
+
     h = hashlib.sha256()
     for leaf in jax.tree.leaves(tree):
         h.update(np.asarray(leaf).tobytes())
@@ -82,6 +83,8 @@ def save_train_checkpoint(directory: str, step: int, device_state: Any,
     """Write one checkpoint; returns the .npz path. ``LATEST`` is updated
     last (atomic rename) so a crash mid-write never corrupts the newest
     resumable step."""
+    from repro.checkpoint.store import save_pytree
+
     os.makedirs(directory, exist_ok=True)
     save_pytree(device_state, _npz_path(directory, step))
     tmp = _json_path(directory, step) + ".tmp"
@@ -132,6 +135,10 @@ def load_train_checkpoint(
     shapes, dtypes - and, when already placed on a mesh, the shardings to
     restore onto unless ``shardings`` overrides them).
     """
+    import jax
+
+    from repro.checkpoint.store import load_pytree
+
     if step is None:
         step = latest_checkpoint_step(directory)
         if step is None:

@@ -67,6 +67,8 @@ class TrainResult:
     episode_violation: list = field(default_factory=list)
     states_explored: list = field(default_factory=list)  # cumulative distinct
     metrics: list = field(default_factory=list)
+    # compiles of train_sac's fused chunk over the whole run (1 = no retrace)
+    trace_count: int = 0
 
 
 # transition fields persisted to the SAC replay buffer
@@ -297,6 +299,7 @@ def train_sac(
         _save(ep)
 
     result.params = params  # type: ignore[attr-defined]
+    result.trace_count = chunk.trace_count[0]
     return result
 
 
@@ -319,3 +322,37 @@ def evaluate_sac(env: MHSLEnv, params, cfg: SAC.SACConfig, episodes: int = 20,
         "reward": float(jnp.sum(traj["reward"])) / episodes,
         "leak": float(jnp.sum(traj["leak"])) / episodes,
     }
+
+
+def rollout_plan(env: MHSLEnv, params, cfg: SAC.SACConfig, seed: int = 7):
+    """Roll the trained policy through one episode and read off its plan.
+
+    Returns ``(boundaries, devices, leaked, t_r, e_r)``: the split plan's
+    cumulative cut points and stage devices at the end of the episode,
+    the information leaked along the way, and the time and energy budget
+    left at its end (negative = budget overrun).
+    """
+    key = jax.random.PRNGKey(seed)
+    st = env.reset(jax.random.PRNGKey(0))
+    pair_dim = env.obs_dim + A.flat_dim(env.action_dims)
+    hist = jnp.zeros((cfg.hist_len, pair_dim))
+    hmask = jnp.zeros((cfg.hist_len,))
+    leaked = 0.0
+    for _ in range(env.episode_len):
+        key, ka, ks = jax.random.split(key, 3)
+        obs = env.observe(st)
+        masks = env.action_masks(st)
+        a = SAC.select_action(params, ka, obs, hist, hmask, masks,
+                              env.action_dims, cfg)
+        pair = jnp.concatenate([obs, A.onehot(a, env.action_dims)])
+        hist = jnp.roll(hist, -1, axis=0).at[-1].set(pair)
+        hmask = jnp.roll(hmask, -1).at[-1].set(1.0)
+        st, _, _, info = env.step(st, a, ks)
+        leaked += float(info["leak"])
+    return (
+        tuple(int(b) for b in np.asarray(st.boundaries)),
+        tuple(int(d) for d in np.asarray(st.stage_dev)),
+        leaked,
+        float(st.t_r),
+        float(st.e_r),
+    )

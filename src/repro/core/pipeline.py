@@ -80,7 +80,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.launch.mesh import make_stage_mesh  # noqa: F401  (re-export)
@@ -416,7 +415,7 @@ def pipeline_loss_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
 
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         data_spec = P(None, env_axis) if env_axis is not None else P()
-        loss = shard_map(
+        loss = jax.shard_map(
             per_stage,
             mesh=mesh,
             in_specs=(
@@ -424,7 +423,7 @@ def pipeline_loss_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 data_spec, data_spec, P(), P(), P(),
             ),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(stage_blocks, tok_mb, lab_mb, params["embed"], params["final_norm"], head)
         return loss
 
@@ -496,6 +495,12 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
     wdtype = pipe.wire
     overlap = pipe.transport == "overlap"
     env_size = int(mesh.shape[env_axis]) if env_axis is not None else 1
+    # tied embeddings: the LM head IS the (V, d) embedding table, read in
+    # its own layout (no transposed copy), and its loss gradient lands in
+    # the embedding-gradient accumulator - at a 152k vocabulary each extra
+    # (V, d) f32 buffer is 1.2 GB of a 16 GB chip
+    tied = cfg.tie_embeddings
+    head_spec = "bsd,vd->bsv" if tied else "bsd,dv->bsv"
 
     def fn(params, tokens, labels):
         if mixed:
@@ -512,7 +517,7 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 f"microbatch size {mb} must divide over env axis ({env_size})")
         tok_mb = tokens.reshape(m_micro, mb, t_len)
         lab_mb = labels.reshape(m_micro, mb, t_len)
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        head = params["embed"] if tied else params["lm_head"]
 
         def per_stage(stage_blocks, codes_st, lens_arr, tok_mb, lab_mb, embed,
                       final_norm, head):
@@ -570,15 +575,15 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
             def stage_loss(blocks, fnorm, hd, x, lab):
                 y = stage_fwd(blocks, x)
                 xh = L.rms_norm(y, fnorm, cfg.norm_eps)
-                logits = jnp.einsum("bsd,dv->bsv", xh, hd.astype(y.dtype))
+                logits = jnp.einsum(head_spec, xh, hd.astype(y.dtype))
                 return M.softmax_xent(logits, lab)
 
-            zero_blocks = jax.tree.map(jnp.zeros_like, stage_blocks)
             perm_f = [(i, (i + 1) % s_stages) for i in range(s_stages)]
             perm_b = [(i, (i - 1) % s_stages) for i in range(s_stages)]
 
             def tick(carry, t):
-                buf_x, buf_g, stash, gblocks, gembed, gnorm, ghead, loss_acc = carry
+                # acc = (gblocks, gembed, gnorm, ghead, loss_acc)
+                buf_x, buf_g, stash, acc = carry
 
                 # ---- the hops (Eq. 1 forward, Eq. 4 gradient) -------------
                 # overlap: the carry holds LAST tick's wire-dtype send
@@ -634,41 +639,45 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 lab = lab_mb[mb_c]
                 toksb = tok_mb[mb_c]
 
+                # the accumulators ride INTO the conds and come back
+                # updated: a branch that does no work hands them back
+                # untouched instead of materializing weight-sized zeros
                 def run_bwd(operand):
-                    x_sv, g, lb = operand
+                    x_sv, g, lb, acc = operand
 
-                    def last_branch(_):
+                    def last_branch(acc):
+                        gblocks, gembed, gnorm, ghead, loss_acc = acc
                         li, vjp = jax.vjp(
                             lambda bl, fn_, hd_, xx: stage_loss(bl, fn_, hd_, xx, lb),
                             stage_blocks, final_norm, head, x_sv,
                         )
                         dbl, dfn, dhd, dx = vjp(jnp.asarray(1.0 / m_micro, jnp.float32))
-                        return li, dbl, dfn, dhd, dx
+                        if tied:
+                            gembed = gembed + dhd
+                        else:
+                            ghead = ghead + dhd
+                        return (jax.tree.map(jnp.add, gblocks, dbl), gembed,
+                                gnorm + dfn, ghead, loss_acc + li), dx
 
-                    def mid_branch(_):
+                    def mid_branch(acc):
+                        gblocks, gembed, gnorm, ghead, loss_acc = acc
                         _, vjp = jax.vjp(
                             lambda bl, xx: stage_fwd(bl, xx), stage_blocks, x_sv
                         )
                         dbl, dx = vjp(g)
-                        return (jnp.zeros((), jnp.float32), dbl,
-                                jnp.zeros_like(final_norm), jnp.zeros_like(head),
-                                dx)
+                        return (jax.tree.map(jnp.add, gblocks, dbl), gembed,
+                                gnorm, ghead, loss_acc), dx
 
-                    return jax.lax.cond(is_last, last_branch, mid_branch, None)
+                    return jax.lax.cond(is_last, last_branch, mid_branch, acc)
 
                 def skip_bwd(operand):
-                    x_sv, g, _lb = operand
-                    return (jnp.zeros((), jnp.float32), zero_blocks,
-                            jnp.zeros_like(final_norm), jnp.zeros_like(head),
-                            jnp.zeros_like(g))
+                    x_sv, g, _lb, acc = operand
+                    return acc, jnp.zeros_like(g)
 
-                li, dbl, dfn, dhd, dx = jax.lax.cond(
-                    b_valid, run_bwd, skip_bwd, (x_saved, g_in, lab)
+                acc, dx = jax.lax.cond(
+                    b_valid, run_bwd, skip_bwd, (x_saved, g_in, lab, acc)
                 )
-                gblocks = jax.tree.map(jnp.add, gblocks, dbl)
-                gnorm = gnorm + dfn
-                ghead = ghead + dhd
-                loss_acc = loss_acc + li
+                gblocks, gembed, gnorm, ghead, loss_acc = acc
                 # stage 0's dx is the cotangent of the embedding lookup;
                 # the full-vocab scatter-add is cond-gated like the other
                 # idle slots (it would otherwise run masked-to-zero on
@@ -679,6 +688,7 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                     lambda ge: ge,
                     gembed,
                 )
+                acc = (gblocks, gembed, gnorm, ghead, loss_acc)
 
                 if overlap:
                     # stage outputs become NEXT tick's in-flight buffers
@@ -690,24 +700,24 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                         y.astype(wdtype), stage_axis, perm_f).astype(pipe.dtype)
                     g_next = jax.lax.ppermute(
                         dx.astype(wdtype), stage_axis, perm_b).astype(pipe.dtype)
-                return (x_next, g_next, stash, gblocks, gembed, gnorm, ghead,
-                        loss_acc), None
+                return (x_next, g_next, stash, acc), None
 
             buf_dtype = wdtype if overlap else pipe.dtype
             x0 = jnp.zeros((mb, t_len, cfg.d_model), buf_dtype)
             g0 = jnp.zeros_like(x0)
             stash0 = jnp.zeros((depth, mb, t_len, cfg.d_model), pipe.dtype)
-            carry0 = (
-                x0, g0, stash0,
+            acc0 = (
                 jax.tree.map(jnp.zeros_like, stage_blocks),
                 jnp.zeros_like(embed),
                 jnp.zeros_like(final_norm),
-                jnp.zeros_like(head),
+                # tied: head grads go to gembed; a scalar placeholder here
+                jnp.zeros((), jnp.float32) if tied else jnp.zeros_like(head),
                 jnp.zeros((), jnp.float32),
             )
-            (_, _, _, gblocks, gembed, gnorm, ghead, loss_acc), _ = jax.lax.scan(
-                tick, carry0, jnp.arange(n_ticks)
+            (_, _, _, acc), _ = jax.lax.scan(
+                tick, (x0, g0, stash0, acc0), jnp.arange(n_ticks)
             )
+            gblocks, gembed, gnorm, ghead, loss_acc = acc
             loss = jax.lax.psum(loss_acc, stage_axis) / m_micro
             gembed = jax.lax.psum(gembed, stage_axis)
             gnorm = jax.lax.psum(gnorm, stage_axis)
@@ -724,7 +734,7 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                     gnorm, ghead)
 
         data_spec = P(None, env_axis) if env_axis is not None else P()
-        loss, gstages, gembed, gnorm, ghead = shard_map(
+        loss, gstages, gembed, gnorm, ghead = jax.shard_map(
             per_stage,
             mesh=mesh,
             in_specs=(
@@ -737,7 +747,7 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 jax.tree.map(lambda _: P(stage_axis), stage_blocks),
                 P(), P(), P(),
             ),
-            check_rep=False,
+            check_vma=False,
         )(stage_blocks, codes_st, lens_arr, tok_mb, lab_mb, params["embed"],
           params["final_norm"], head)
 
@@ -748,10 +758,8 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
         else:
             grads["slots"] = (union_grads,)
         grads["final_norm"] = gnorm
-        if cfg.tie_embeddings:
-            grads["embed"] = gembed + ghead.T
-        else:
-            grads["embed"] = gembed
+        grads["embed"] = gembed
+        if not tied:
             grads["lm_head"] = ghead
         return loss, grads
 
@@ -924,7 +932,7 @@ def pipeline_serve_fns(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 stage_axis)
             return logits, ck[None], cv[None]
 
-        logits, ck, cv = shard_map(
+        logits, ck, cv = jax.shard_map(
             per_stage,
             mesh=mesh,
             in_specs=(
@@ -933,7 +941,7 @@ def pipeline_serve_fns(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 P(), P(), P(), P(),
             ),
             out_specs=(P(), P(stage_axis), P(stage_axis)),
-            check_rep=False,
+            check_vma=False,
         )(stage_blocks, codes_st, lens_arr, caches["k"], caches["v"], x,
           params["embed"], params["final_norm"], head)
         return logits, {"k": ck, "v": cv}

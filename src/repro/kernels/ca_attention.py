@@ -22,9 +22,10 @@ batch tile:
   * rows with no valid history attend to nothing and emit zeros, exactly
     like the reference's ``any_valid`` guard.
 
-``interpret=True`` (the default) executes the kernel body on CPU for
-parity testing against ``agents.attention.cross_attention``
-(``tests/test_kernels.py``); pass ``interpret=False`` on TPU.
+``interpret=None`` (the default) resolves from the backend: the compiled
+kernel on TPU, the Pallas interpreter elsewhere - which is how
+``tests/test_kernels.py`` checks parity against
+``agents.attention.cross_attention`` on the CPU.
 """
 from __future__ import annotations
 

@@ -12,6 +12,8 @@ TPU-native design:
     visits them, but no MXU work is issued).
 
 Validated in interpret mode against ``ref.flash_attention_ref``.
+``interpret=None`` resolves from the backend: the compiled kernel on TPU,
+the Pallas interpreter everywhere else.
 """
 from __future__ import annotations
 
@@ -106,8 +108,10 @@ def flash_attention(
     q_blk: int = 128,
     kv_blk: int = 128,
     q_offset: int = 0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, sq, h, hd = q.shape
     skv, kh = k.shape[1], k.shape[2]
     g = h // kh

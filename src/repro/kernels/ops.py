@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container ``interpret=True`` executes the kernel bodies in
-Python for correctness validation; on TPU pass ``interpret=False``.
+Every kernel takes ``interpret=None`` by default and resolves it from the
+backend: the compiled kernel on TPU, the Pallas interpreter (which runs
+the kernel bodies for correctness validation) everywhere else.
 """
 from repro.kernels.ca_attention import ca_attention
 from repro.kernels.flash_attention import flash_attention

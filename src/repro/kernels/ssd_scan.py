@@ -12,10 +12,13 @@ TPU-native design:
     lane multiples by the wrapper when needed.
 
 Validated in interpret mode against ``ref.ssd_scan_ref``.
+``interpret=None`` resolves from the backend: the compiled kernel on TPU,
+the Pallas interpreter everywhere else.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -97,8 +100,10 @@ def ssd_scan(
     c: jax.Array,  # (B, S, N)
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     bsz, s, h, p = x.shape
     n = b.shape[-1]
     pad = (-s) % chunk

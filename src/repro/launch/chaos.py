@@ -15,9 +15,12 @@ BIT-IDENTICAL to a run that was never interrupted. The harness:
    a real preemption would land;
 3. re-launches the SAME command; the child restores the checkpoint
    (``resume=True``) and trains the remaining episodes;
-4. compares the resumed metrics against an uninterrupted in-process
-   reference run, element-for-element (floats compared by equality, not
-   tolerance).
+4. compares the resumed metrics against an uninterrupted reference run,
+   element-for-element (floats compared by equality, not tolerance). The
+   reference is a child too (no ``--dir``: no checkpoints, no kill).
+
+The parent never imports JAX: on an accelerator a chip belongs to one
+process, and the parent holding it would starve every child.
 
 ``--seeds`` runs the whole dance once per seed (the CI chaos-smoke
 matrix). Exit code 0 = every seed bit-identical.
@@ -35,7 +38,8 @@ from typing import List, Optional
 
 
 def _child_main(args) -> None:
-    """Subprocess body: one checkpointed train_sac run, metrics to stdout."""
+    """Subprocess body: one train_sac run, metrics to stdout. Checkpoints
+    under ``--dir`` when given; without it, the uninterrupted reference."""
     from repro.core.agents.loops import train_sac
     from repro.core.agents.sac import SACConfig
     from repro.core.env import MHSLEnv
@@ -54,14 +58,17 @@ def _child_main(args) -> None:
     }), flush=True)
 
 
-def _child_cmd(args, ckpt_dir: str) -> List[str]:
-    return [
+def _child_cmd(args, ckpt_dir: Optional[str]) -> List[str]:
+    cmd = [
         sys.executable, "-m", "repro.launch.chaos", "--child",
-        "--dir", ckpt_dir, "--seed", str(args.seed),
+        "--seed", str(args.seed),
         "--episodes", str(args.episodes), "--warmup", str(args.warmup),
         "--num-envs", str(args.num_envs),
         "--checkpoint-every", str(args.checkpoint_every),
     ]
+    if ckpt_dir is not None:
+        cmd += ["--dir", ckpt_dir]
+    return cmd
 
 
 def _parse_metrics(stdout: str) -> dict:
@@ -119,22 +126,13 @@ def kill_and_resume(args, ckpt_dir: str) -> dict:
 
 
 def reference_metrics(args) -> dict:
-    """The uninterrupted run, in-process (same code path, no faults)."""
-    from repro.core.agents.loops import train_sac
-    from repro.core.agents.sac import SACConfig
-    from repro.core.env import MHSLEnv
-    from repro.core.profiles import resnet101_profile
-
-    env = MHSLEnv(profile=resnet101_profile(batch=1))
-    res = train_sac(env, SACConfig(), episodes=args.episodes,
-                    seed=args.seed, warmup_episodes=args.warmup,
-                    num_envs=args.num_envs)
-    return {
-        "episode_reward": res.episode_reward,
-        "episode_leak": res.episode_leak,
-        "episode_violation": res.episode_violation,
-        "states_explored": res.states_explored,
-    }
+    """The uninterrupted run, in a child (same code path, no checkpoints,
+    no faults)."""
+    ref = _launch(_child_cmd(args, None))
+    out, _ = ref.communicate(timeout=args.timeout)
+    if ref.returncode != 0:
+        raise RuntimeError(f"reference run exited {ref.returncode}:\n{out}")
+    return _parse_metrics(out)
 
 
 def compare(resumed: dict, reference: dict) -> List[str]:
@@ -152,7 +150,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--child", action="store_true",
                     help="internal: run the training child process")
     ap.add_argument("--dir", default=None,
-                    help="checkpoint directory (child) / scratch root")
+                    help="checkpoint directory (child; omit for the "
+                         "uninterrupted reference) / scratch root")
     ap.add_argument("--seed", type=int, default=5)
     ap.add_argument("--seeds", default=None,
                     help="comma-separated seed matrix (overrides --seed)")
@@ -167,8 +166,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = ap.parse_args(argv)
 
     if args.child:
-        if args.dir is None:
-            ap.error("--child requires --dir")
         _child_main(args)
         return 0
 
@@ -204,4 +201,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

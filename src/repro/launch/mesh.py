@@ -10,21 +10,13 @@ Target hardware: TPU v5e pods, 256 chips/pod (16x16 ICI torus); multi-pod =
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer releases; every axis here
-    is Auto anyway, which is also the default where the kwarg exists."""
-    if "axis_types" in inspect.signature(jax.make_mesh).parameters and hasattr(
-        jax.sharding, "AxisType"
-    ):
-        types = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=types)
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (GSPMD-partitioned)."""
+    types = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

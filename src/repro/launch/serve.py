@@ -45,12 +45,11 @@ def run_static(cfg, trace, *, warmup: bool = False):
     import jax
     import jax.numpy as jnp
 
-    from repro.models import init_params
     from repro.serving.batching import make_generate_fn
     from repro.serving.runners import PipelineRunner, SingleDeviceRunner
 
     model_cfg = cfg.model_config()
-    params = init_params(jax.random.PRNGKey(cfg.seed), model_cfg)
+    params = cfg.init_params()
     dtype = jnp.dtype(cfg.compute_dtype)
     if cfg.boundaries is None:
         runner = SingleDeviceRunner(model_cfg, compute_dtype=dtype)
@@ -178,4 +177,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

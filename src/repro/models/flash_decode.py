@@ -14,7 +14,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distribution import context as ctx
@@ -72,10 +71,10 @@ def flash_decode(
     qspec = P(batch_ax, None, None, None)
     cspec = P(batch_ax, model_ax, None, None)
     ispec = P(batch_ax) if vec_idx else P()
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(qspec, cspec, cspec, ispec),
         out_specs=qspec,
-        check_rep=False,
+        check_vma=False,
     )(q, ck, cv, cache_index)

@@ -365,7 +365,7 @@ def attention_apply(
             from repro.kernels import ops as kops
 
             out = kops.flash_attention(
-                q, k, v, causal=True, window=cfg.attention_window, interpret=True
+                q, k, v, causal=True, window=cfg.attention_window
             )
         elif use_chunked:
             out = chunked_attention(q, k, v, q_offset=0, window=cfg.attention_window)

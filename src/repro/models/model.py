@@ -330,13 +330,14 @@ def softmax_xent(logits: Array, labels: Array, mask: Optional[Array] = None):
     return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
-def loss_fn(params, batch, cfg: ModelConfig, *, impl="auto", remat=True, unroll=False):
+def loss_fn(params, batch, cfg: ModelConfig, *, impl="auto", remat=True, unroll=False,
+            compute_dtype=jnp.bfloat16):
     tokens = batch["tokens"]
     labels = batch["labels"]
     frontend = batch.get("frontend")
     logits, _, aux = forward(
         params, tokens, cfg, frontend_feats=frontend, impl=impl, remat=remat,
-        unroll=unroll,
+        unroll=unroll, compute_dtype=compute_dtype,
     )
     if frontend is not None:
         # loss only over the text region (frontend positions are prefix)

@@ -25,7 +25,6 @@ from typing import Tuple
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -115,12 +114,12 @@ def moe_apply_a2a(params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, ja
 
     xspec = P(batch_ax, None, None)
     wspec = P(model_ax, None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(xspec, P(), wspec, wspec, wspec),
         out_specs=(xspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(
         x,
         params["router"],

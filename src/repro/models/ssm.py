@@ -195,7 +195,7 @@ def mamba_apply(params, x: Array, cfg: ModelConfig, *, ssm_state=None, conv_stat
 
         y, new_ssm = kops.ssd_scan(
             xh.astype(jnp.float32), dt, a, bmat.astype(jnp.float32),
-            cmat.astype(jnp.float32), chunk=sc.chunk, interpret=True,
+            cmat.astype(jnp.float32), chunk=sc.chunk,
         )
     else:
         y, new_ssm = ssd_chunked(

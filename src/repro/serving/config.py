@@ -69,6 +69,19 @@ class ServeConfig:
             cfg = dataclasses.replace(cfg, num_layers=self.num_layers)
         return cfg
 
+    def init_params(self):
+        """Random weights from ``seed``, built in ``compute_dtype`` under
+        ``jax.jit``: the f32 draws fuse into their casts, so a bf16 model
+        at published widths never holds an f32 copy on the device."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.models.model import init_params
+
+        return jax.jit(init_params, static_argnums=(1, 2))(
+            jax.random.PRNGKey(self.seed), self.model_config(),
+            jnp.dtype(self.compute_dtype))
+
     @classmethod
     def load(cls, path: Optional[str] = None,
              overrides: Optional[dict] = None) -> "ServeConfig":

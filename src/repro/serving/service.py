@@ -139,7 +139,6 @@ class ServingService:
         import jax
         import jax.numpy as jnp
 
-        from repro.models import model as M
         from repro.serving.engine import (init_engine_state,
                                           make_engine_step)
         from repro.serving.runners import PipelineRunner, SingleDeviceRunner
@@ -160,10 +159,7 @@ class ServingService:
                                   wire_dtype=cfg.wire_dtype)
             self.runner = PipelineRunner(self.model_cfg, mesh,
                                          cfg.boundaries, pipe=pipe)
-        if params is None:
-            params = M.init_params(jax.random.PRNGKey(cfg.seed),
-                                   self.model_cfg)
-        self.params = params
+        self.params = cfg.init_params() if params is None else params
         self.base_key = jax.random.PRNGKey(cfg.seed)
         self.step = make_engine_step(
             self.runner, num_slots=cfg.num_slots,

@@ -47,6 +47,34 @@ def test_sigkill_resume_metrics_bit_identical(tmp_path):
     assert rc == 0
 
 
+def test_chaos_parent_never_imports_jax(tmp_path):
+    """On an accelerator a chip belongs to one process: the harness's
+    parent must stay off JAX so its training children can hold the chip.
+    Everything the parent runs - the module, checkpoint polling, the
+    compile-cache rule - imports no JAX."""
+    import subprocess
+    import sys
+
+    from conftest import REPO, SRC
+
+    code = f"""
+import sys
+from repro.launch import chaos
+from repro.launch.compile_cache import enable_compile_cache
+from repro.checkpoint.train_state import latest_checkpoint_step
+enable_compile_cache()
+assert latest_checkpoint_step({str(tmp_path)!r}) is None
+assert "jax" not in sys.modules, sorted(m for m in sys.modules if "jax" in m)
+print("JAX_FREE")
+"""
+    env = dict(os.environ, PYTHONPATH=SRC,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, env=env, cwd=REPO)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "JAX_FREE" in out.stdout
+
+
 # ---------------------------------------------------------------------------
 # fault-injected serving: untouched requests bitwise, zero retraces
 
