@@ -502,6 +502,9 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
     tied = cfg.tie_embeddings
     head_spec = "bsd,vd->bsv" if tied else "bsd,dv->bsv"
 
+    # the whole step, so the stage re-layout and the schedule's own loop
+    # are attributed too; the 1F1B slots below carry their own scopes
+    @jax.named_scope("pipeline.step")
     def fn(params, tokens, labels):
         if mixed:
             layer_stack = union_layer_params(params["slots"], cfg.num_layers)
@@ -574,9 +577,10 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
 
             def stage_loss(blocks, fnorm, hd, x, lab):
                 y = stage_fwd(blocks, x)
-                xh = L.rms_norm(y, fnorm, cfg.norm_eps)
-                logits = jnp.einsum(head_spec, xh, hd.astype(y.dtype))
-                return M.softmax_xent(logits, lab)
+                with jax.named_scope("pipeline.head"):
+                    xh = L.rms_norm(y, fnorm, cfg.norm_eps)
+                    logits = jnp.einsum(head_spec, xh, hd.astype(y.dtype))
+                    return M.softmax_xent(logits, lab)
 
             perm_f = [(i, (i + 1) % s_stages) for i in range(s_stages)]
             perm_b = [(i, (i - 1) % s_stages) for i in range(s_stages)]
@@ -592,42 +596,44 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 # collective-permute-start/done pairs that run under the
                 # slot that does not consume them.
                 if overlap:
-                    x_in = jax.lax.ppermute(
-                        buf_x, stage_axis, perm_f).astype(pipe.dtype)
-                    g_in = jax.lax.ppermute(
-                        buf_g, stage_axis, perm_b).astype(pipe.dtype)
+                    with jax.named_scope("pipeline.hop"):
+                        x_in = jax.lax.ppermute(
+                            buf_x, stage_axis, perm_f).astype(pipe.dtype)
+                        g_in = jax.lax.ppermute(
+                            buf_g, stage_axis, perm_b).astype(pipe.dtype)
                 else:
                     x_in, g_in = buf_x, buf_g
 
                 # ---- forward slot: microbatch t - i -----------------------
-                mf = t - sidx
-                f_valid = (mf >= 0) & (mf < m_micro)
-                # the embedding gather is stage 0's alone - cond it out on
-                # the other S-1 stages instead of masking it to zeros
-                x0 = jax.lax.cond(
-                    is_first,
-                    lambda xx: embed[
-                        tok_mb[jnp.clip(mf, 0, m_micro - 1)]
-                    ].astype(xx.dtype),
-                    lambda xx: xx,
-                    x_in,
-                )
-                stash = jax.lax.cond(
-                    f_valid,
-                    lambda st: jax.lax.dynamic_update_index_in_dim(
-                        st, x0, jnp.mod(mf, depth), 0
-                    ),
-                    lambda st: st,
-                    stash,
-                )
-                # the last stage's forward happens inside its loss VJP, so
-                # its forward slot only stashes
-                y = jax.lax.cond(
-                    f_valid & (~is_last),
-                    lambda xx: stage_fwd(stage_blocks, xx),
-                    lambda xx: xx,
-                    x0,
-                )
+                with jax.named_scope("pipeline.fwd"):
+                    mf = t - sidx
+                    f_valid = (mf >= 0) & (mf < m_micro)
+                    # the embedding gather is stage 0's alone - cond it out
+                    # on the other S-1 stages instead of masking it to zeros
+                    x0 = jax.lax.cond(
+                        is_first,
+                        lambda xx: embed[
+                            tok_mb[jnp.clip(mf, 0, m_micro - 1)]
+                        ].astype(xx.dtype),
+                        lambda xx: xx,
+                        x_in,
+                    )
+                    stash = jax.lax.cond(
+                        f_valid,
+                        lambda st: jax.lax.dynamic_update_index_in_dim(
+                            st, x0, jnp.mod(mf, depth), 0
+                        ),
+                        lambda st: st,
+                        stash,
+                    )
+                    # the last stage's forward happens inside its loss VJP,
+                    # so its forward slot only stashes
+                    y = jax.lax.cond(
+                        f_valid & (~is_last),
+                        lambda xx: stage_fwd(stage_blocks, xx),
+                        lambda xx: xx,
+                        x0,
+                    )
 
                 # ---- backward slot: microbatch t - 2(S-1) + i -------------
                 mbk = t - 2 * (s_stages - 1) + sidx
@@ -652,12 +658,14 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                             stage_blocks, final_norm, head, x_sv,
                         )
                         dbl, dfn, dhd, dx = vjp(jnp.asarray(1.0 / m_micro, jnp.float32))
-                        if tied:
-                            gembed = gembed + dhd
-                        else:
-                            ghead = ghead + dhd
-                        return (jax.tree.map(jnp.add, gblocks, dbl), gembed,
-                                gnorm + dfn, ghead, loss_acc + li), dx
+                        with jax.named_scope("pipeline.accum"):
+                            if tied:
+                                gembed = gembed + dhd
+                            else:
+                                ghead = ghead + dhd
+                            return (jax.tree.map(jnp.add, gblocks, dbl),
+                                    gembed, gnorm + dfn, ghead,
+                                    loss_acc + li), dx
 
                     def mid_branch(acc):
                         gblocks, gembed, gnorm, ghead, loss_acc = acc
@@ -665,8 +673,9 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                             lambda bl, xx: stage_fwd(bl, xx), stage_blocks, x_sv
                         )
                         dbl, dx = vjp(g)
-                        return (jax.tree.map(jnp.add, gblocks, dbl), gembed,
-                                gnorm, ghead, loss_acc), dx
+                        with jax.named_scope("pipeline.accum"):
+                            return (jax.tree.map(jnp.add, gblocks, dbl),
+                                    gembed, gnorm, ghead, loss_acc), dx
 
                     return jax.lax.cond(is_last, last_branch, mid_branch, acc)
 
@@ -674,20 +683,22 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                     x_sv, g, _lb, acc = operand
                     return acc, jnp.zeros_like(g)
 
-                acc, dx = jax.lax.cond(
-                    b_valid, run_bwd, skip_bwd, (x_saved, g_in, lab, acc)
-                )
+                with jax.named_scope("pipeline.bwd"):
+                    acc, dx = jax.lax.cond(
+                        b_valid, run_bwd, skip_bwd, (x_saved, g_in, lab, acc)
+                    )
                 gblocks, gembed, gnorm, ghead, loss_acc = acc
                 # stage 0's dx is the cotangent of the embedding lookup;
                 # the full-vocab scatter-add is cond-gated like the other
                 # idle slots (it would otherwise run masked-to-zero on
                 # every stage every tick)
-                gembed = jax.lax.cond(
-                    b_valid & is_first,
-                    lambda ge: ge.at[toksb].add(dx.astype(ge.dtype)),
-                    lambda ge: ge,
-                    gembed,
-                )
+                with jax.named_scope("pipeline.accum"):
+                    gembed = jax.lax.cond(
+                        b_valid & is_first,
+                        lambda ge: ge.at[toksb].add(dx.astype(ge.dtype)),
+                        lambda ge: ge,
+                        gembed,
+                    )
                 acc = (gblocks, gembed, gnorm, ghead, loss_acc)
 
                 if overlap:
@@ -696,10 +707,11 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                     g_next = dx.astype(wdtype)
                 else:
                     # synchronous handoff: hop now, on this tick's outputs
-                    x_next = jax.lax.ppermute(
-                        y.astype(wdtype), stage_axis, perm_f).astype(pipe.dtype)
-                    g_next = jax.lax.ppermute(
-                        dx.astype(wdtype), stage_axis, perm_b).astype(pipe.dtype)
+                    with jax.named_scope("pipeline.hop"):
+                        x_next = jax.lax.ppermute(y.astype(wdtype), stage_axis,
+                                                  perm_f).astype(pipe.dtype)
+                        g_next = jax.lax.ppermute(dx.astype(wdtype), stage_axis,
+                                                  perm_b).astype(pipe.dtype)
                 return (x_next, g_next, stash, acc), None
 
             buf_dtype = wdtype if overlap else pipe.dtype
@@ -906,13 +918,19 @@ def pipeline_serve_fns(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                         xi, ki, vi = op
                         return apply_block(blk, code, xi, ki, vi)
 
-                    xc, k_i, v_i = jax.lax.cond(
-                        i < active_len, apply, lambda op: op, (xc, k_i, v_i))
+                    with jax.named_scope("model.block"):
+                        xc, k_i, v_i = jax.lax.cond(
+                            i < active_len, apply, lambda op: op,
+                            (xc, k_i, v_i))
                     return (xc,), (k_i, v_i)
 
-                (xx,), (nk, nv) = jax.lax.scan(
-                    body, (xx,), (stage_blocks, ck, cv, codes,
-                                  jnp.arange(max_len)))
+                # as in models.model.forward: the scan's own slicing and
+                # stacking of the stage's cache is model.layers less
+                # model.block
+                with jax.named_scope("model.layers"):
+                    (xx,), (nk, nv) = jax.lax.scan(
+                        body, (xx,), (stage_blocks, ck, cv, codes,
+                                      jnp.arange(max_len)))
                 return xx, nk, nv
 
             for t in range(s_stages):

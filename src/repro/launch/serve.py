@@ -13,6 +13,9 @@
     PYTHONPATH=src python -m repro.launch.serve --config serve.json \
         --set num_slots 16 --set decode_chunk 4
 
+    # the service loop's spans and request times as JSON lines
+    PYTHONPATH=src python -m repro.launch.serve --trace-out serve.jsonl
+
 Every engine/scheduler knob is a :class:`repro.serving.ServeConfig`
 field: the launcher loads ``--config`` (JSON), applies ``--set key
 value`` overrides, and runs. ``--mode static`` runs the same trace
@@ -140,6 +143,10 @@ def main(argv=None):
     ap.add_argument("--trace-seed", type=int, default=0)
     ap.add_argument("--json", action="store_true",
                     help="emit metrics as one JSON line")
+    ap.add_argument("--trace-out", default=None, metavar="PATH",
+                    help="engine mode: record the service loop's spans "
+                         "(repro.tracing) and write them to PATH as JSON "
+                         "lines")
     args = ap.parse_args(argv)
 
     from repro.serving import ServeConfig, poisson_trace
@@ -157,9 +164,14 @@ def main(argv=None):
         res = run_static(cfg, trace)
     else:
         from repro.serving import ServingService
+        from repro.tracing import NULL_TRACER, Tracer
 
-        svc = ServingService(cfg)
-        res = svc.run(trace)
+        tracer = Tracer() if args.trace_out else NULL_TRACER
+        with tracer:
+            svc = ServingService(cfg, tracer=tracer)
+            res = svc.run(trace)
+        if args.trace_out:
+            tracer.dump(args.trace_out)
 
     metrics = {k: v for k, v in res.items()
                if k not in ("completions", "latencies", "replans")}
@@ -173,6 +185,10 @@ def main(argv=None):
         print(f"  p50 {res['p50_latency_s']*1e3:.0f} ms  "
               f"p99 {res['p99_latency_s']*1e3:.0f} ms  "
               f"slot occupancy {res['slot_occupancy']:.2f}")
+        if "ttft_p50_s" in res:
+            print(f"  ttft p50 {res['ttft_p50_s']*1e3:.0f} ms  "
+                  f"p95 {res['ttft_p95_s']*1e3:.0f} ms  "
+                  f"queue wait p95 {res['queue_wait_p95_s']*1e3:.0f} ms")
     return res
 
 

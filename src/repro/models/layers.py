@@ -225,9 +225,16 @@ def _row_cache_update(cache: Array, fresh: Array, index: Array) -> Array:
     slots sitting at different sequence positions (the serving engine's
     per-slot KV rings); the scalar-``cache_index`` path is untouched.
     """
-    return jax.vmap(
-        lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
-    )(cache, fresh, index)
+    with jax.named_scope("model.kv_write"):
+        return jax.vmap(
+            lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
+        )(cache, fresh, index)
+
+
+def _scalar_cache_update(cache: Array, fresh: Array, index) -> Array:
+    """Every row of ``cache`` takes ``fresh`` at the same ``index``."""
+    with jax.named_scope("model.kv_write"):
+        return jax.lax.dynamic_update_slice(cache, fresh, (0, index, 0, 0))
 
 
 def attention_apply(
@@ -296,10 +303,8 @@ def attention_apply(
                 abs_pos = t[:, None] - jnp.mod(t[:, None] - idx[None, :], cache_len)
                 kpos_bias = jnp.where(abs_pos >= 0, 0.0, -jnp.inf)[:, None, None, :]
             else:
-                ck = jax.lax.dynamic_update_slice(
-                    kv_cache["k"], k.astype(cd), (0, slot, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    kv_cache["v"], v.astype(cd), (0, slot, 0, 0))
+                ck = _scalar_cache_update(kv_cache["k"], k.astype(cd), slot)
+                cv = _scalar_cache_update(kv_cache["v"], v.astype(cd), slot)
                 abs_pos = t - jnp.mod(t - idx, cache_len)
                 kpos_bias = jnp.where(abs_pos >= 0, 0.0, -jnp.inf)[None, None, None, :]
             scores = jnp.einsum(
@@ -317,12 +322,10 @@ def attention_apply(
                 ck = _row_cache_update(kv_cache["k"], k.astype(cd), cache_index)
                 cv = _row_cache_update(kv_cache["v"], v.astype(cd), cache_index)
             else:
-                ck = jax.lax.dynamic_update_slice(
-                    kv_cache["k"], k.astype(cd), (0, cache_index, 0, 0)
-                )
-                cv = jax.lax.dynamic_update_slice(
-                    kv_cache["v"], v.astype(cd), (0, cache_index, 0, 0)
-                )
+                ck = _scalar_cache_update(kv_cache["k"], k.astype(cd),
+                                          cache_index)
+                cv = _scalar_cache_update(kv_cache["v"], v.astype(cd),
+                                          cache_index)
             from repro.distribution.context import active as ctx_active
 
             if (

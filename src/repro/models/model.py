@@ -269,40 +269,46 @@ def forward(
             f = jax.checkpoint(inner, policy=jax.checkpoint_policies.nothing_saveable)
         else:
             f = inner
-        xact, aux, new_caches = f(xact, aux, slot_params, slot_caches)
+        with jax.named_scope("model.block"):
+            xact, aux, new_caches = f(xact, aux, slot_params, slot_caches)
         return (xact, aux), new_caches
 
     caches_xs = tuple({} for _ in range(period))
     aux0 = jnp.zeros((), jnp.float32)
-    if unroll:
-        # python-loop unroll: true per-layer HLO (exact flop/collective
-        # accounting in the dry-run; scan counts the body only once)
-        repeats = cfg.num_layers // period
-        carry = (x, aux0)
-        ys = []
-        for r in range(repeats):
-            sp = jax.tree.map(lambda a: a[r], params["slots"])
-            cc = jax.tree.map(lambda a: a[r], caches) if caches is not None else caches_xs
-            carry, nc = body(carry, (sp, cc))
-            ys.append(nc)
-        (x, aux) = carry
-        if caches is None:
+    # the layer stack: what lies under model.layers and outside model.block
+    # is the scan's own work (slicing each layer's weights and cache out of
+    # the stacks, stacking the new caches), read by the benchmark
+    with jax.named_scope("model.layers"):
+        if unroll:
+            # python-loop unroll: true per-layer HLO (exact flop/collective
+            # accounting in the dry-run; scan counts the body only once)
+            repeats = cfg.num_layers // period
+            carry = (x, aux0)
+            ys = []
+            for r in range(repeats):
+                sp = jax.tree.map(lambda a: a[r], params["slots"])
+                cc = (jax.tree.map(lambda a: a[r], caches)
+                      if caches is not None else caches_xs)
+                carry, nc = body(carry, (sp, cc))
+                ys.append(nc)
+            (x, aux) = carry
+            if caches is None:
+                new_caches = None
+            else:
+                new_caches = jax.tree.map(lambda *zs: jnp.stack(zs), *ys)
+        elif caches is None:
+            (x, aux), _ = jax.lax.scan(
+                lambda c, sp: body(c, (sp, caches_xs)),
+                (x, aux0),
+                params["slots"],
+            )
             new_caches = None
         else:
-            new_caches = jax.tree.map(lambda *zs: jnp.stack(zs), *ys)
-    elif caches is None:
-        (x, aux), _ = jax.lax.scan(
-            lambda c, sp: body(c, (sp, caches_xs)),
-            (x, aux0),
-            params["slots"],
-        )
-        new_caches = None
-    else:
-        (x, aux), new_caches = jax.lax.scan(
-            body,
-            (x, aux0),
-            (params["slots"], caches),
-        )
+            (x, aux), new_caches = jax.lax.scan(
+                body,
+                (x, aux0),
+                (params["slots"], caches),
+            )
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = (

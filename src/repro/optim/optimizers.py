@@ -7,6 +7,10 @@ usual gradient-transformation contract:
     state = opt.init(params)
     updates, state = opt.update(grads, state, params)
     params = apply_updates(params, updates)
+
+Named scopes label their device time: ``optim.clip``
+(``clip_by_global_norm``), ``optim.adamw`` (the moment and update maps)
+and ``optim.apply`` (``apply_updates``).
 """
 from __future__ import annotations
 
@@ -35,7 +39,9 @@ def _tree_zeros_like(tree, dtype=None):
 
 
 def apply_updates(params, updates):
-    return jax.tree.map(lambda p, u: (p + u).astype(p.dtype), params, updates)
+    with jax.named_scope("optim.apply"):
+        return jax.tree.map(lambda p, u: (p + u).astype(p.dtype), params,
+                            updates)
 
 
 def global_norm(tree) -> jax.Array:
@@ -44,9 +50,10 @@ def global_norm(tree) -> jax.Array:
 
 
 def clip_by_global_norm(tree, max_norm: float):
-    norm = global_norm(tree)
-    scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
-    return jax.tree.map(lambda x: x * scale.astype(x.dtype), tree), norm
+    with jax.named_scope("optim.clip"):
+        norm = global_norm(tree)
+        scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
+        return jax.tree.map(lambda x: x * scale.astype(x.dtype), tree), norm
 
 
 def cosine_schedule(base_lr: float, total_steps: int, final_frac: float = 0.1):
@@ -89,27 +96,30 @@ def adamw(
     def update(grads, state: OptState, params=None):
         if max_grad_norm is not None:
             grads, _ = clip_by_global_norm(grads, max_grad_norm)
-        step = state.step + 1
-        stepf = step.astype(jnp.float32)
-        bc1 = 1 - b1**stepf
-        bc2 = 1 - b2**stepf
-        mu = jax.tree.map(
-            lambda m, g: b1 * m + (1 - b1) * g.astype(m.dtype), state.mu, grads
-        )
-        nu = jax.tree.map(
-            lambda v, g: b2 * v + (1 - b2) * jnp.square(g.astype(v.dtype)),
-            state.nu,
-            grads,
-        )
-        lr_t = lr_fn(step)
+        with jax.named_scope("optim.adamw"):
+            step = state.step + 1
+            stepf = step.astype(jnp.float32)
+            bc1 = 1 - b1**stepf
+            bc2 = 1 - b2**stepf
+            mu = jax.tree.map(
+                lambda m, g: b1 * m + (1 - b1) * g.astype(m.dtype),
+                state.mu,
+                grads,
+            )
+            nu = jax.tree.map(
+                lambda v, g: b2 * v + (1 - b2) * jnp.square(g.astype(v.dtype)),
+                state.nu,
+                grads,
+            )
+            lr_t = lr_fn(step)
 
-        def upd(m, v, p):
-            u = -(lr_t * (m / bc1) / (jnp.sqrt(v / bc2) + eps))
-            if weight_decay:
-                u = u - lr_t * weight_decay * p.astype(u.dtype)
-            return u.astype(p.dtype)
+            def upd(m, v, p):
+                u = -(lr_t * (m / bc1) / (jnp.sqrt(v / bc2) + eps))
+                if weight_decay:
+                    u = u - lr_t * weight_decay * p.astype(u.dtype)
+                return u.astype(p.dtype)
 
-        updates = jax.tree.map(upd, mu, nu, params)
+            updates = jax.tree.map(upd, mu, nu, params)
         return updates, OptState(step=step, mu=mu, nu=nu)
 
     return Optimizer(init=init, update=update)

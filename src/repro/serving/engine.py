@@ -13,6 +13,12 @@ so arrivals, completions, and re-plans never retrace: the step stays one
 compiled trace for the whole service lifetime (``step.trace_count``
 audits this, same idiom as ``core.splitting.make_plan_scorer``).
 
+Named scopes (``jax.named_scope``, op metadata only) label the device
+time: ``engine.admit``, ``engine.prefill``, ``engine.decode`` and
+``engine.sample`` (inside the other two); the model adds
+``model.layers`` (its layer scan), ``model.block`` (the scan's body) and
+``model.kv_write`` (its cache writes).
+
 Invariant the bit-identity proof leans on: KV caches only ever hold
 FINITE values. Freed slots are not zeroed - their stale rows are masked
 out of attention by the per-row causal mask, and a masked FINITE value
@@ -134,18 +140,19 @@ def make_engine_step(runner, *, num_slots: int, arrival_slots: int,
         trace_count.append(1)
 
         # ---- admission: pack arrivals into free slots, in-trace --------
-        free = ~state.active
-        order = jnp.cumsum(free.astype(jnp.int32)) - 1     # rank among free
-        take = free & (order < n_arr)                      # (N,)
-        ai = jnp.clip(order, 0, a - 1)                     # arrival row/slot
-        sel = lambda arr, old: jnp.where(take, arr[ai], old)
-        prompt = jnp.where(take[:, None], arr_prompt[ai], state.prompt)
-        plen = sel(arr_plen, state.plen)
-        gen_target = sel(arr_gen, state.gen_target)
-        req_id = sel(arr_req, state.req_id)
-        n_gen = jnp.where(take, 0, state.n_gen)
-        gen_buf = jnp.where(take[:, None], 0, state.gen_buf)
-        active = state.active | take
+        with jax.named_scope("engine.admit"):
+            free = ~state.active
+            order = jnp.cumsum(free.astype(jnp.int32)) - 1  # rank among free
+            take = free & (order < n_arr)                   # (N,)
+            ai = jnp.clip(order, 0, a - 1)                  # arrival row/slot
+            sel = lambda arr, old: jnp.where(take, arr[ai], old)
+            prompt = jnp.where(take[:, None], arr_prompt[ai], state.prompt)
+            plen = sel(arr_plen, state.plen)
+            gen_target = sel(arr_gen, state.gen_target)
+            req_id = sel(arr_req, state.req_id)
+            n_gen = jnp.where(take, 0, state.n_gen)
+            gen_buf = jnp.where(take[:, None], 0, state.gen_buf)
+            active = state.active | take
 
         # ---- prefill sub-step (only the taken rows land) ---------------
         def do_prefill(operand):
@@ -154,18 +161,21 @@ def make_engine_step(runner, *, num_slots: int, arrival_slots: int,
             caches = cache_where(take, new_caches, caches)
             last = jnp.take_along_axis(
                 logits_all, (plen - 1)[:, None, None], axis=1)[:, 0]
-            tok0 = _row_sample(last.astype(jnp.float32), base_key, req_id,
-                               jnp.zeros((n,), jnp.int32), temperature)
+            with jax.named_scope("engine.sample"):
+                tok0 = _row_sample(last.astype(jnp.float32), base_key,
+                                   req_id, jnp.zeros((n,), jnp.int32),
+                                   temperature)
             last_tok = jnp.where(take, tok0, last_tok)
             pos_c = jnp.where(take, plen, pos_c)
             return caches, prompt, last_tok, pos_c
 
         operand = (state.caches, prompt, state.last_tok, state.pos)
-        if skip_idle_prefill:
-            caches, _, last_tok, pos = jax.lax.cond(
-                take.any(), do_prefill, lambda op: op, operand)
-        else:
-            caches, _, last_tok, pos = do_prefill(operand)
+        with jax.named_scope("engine.prefill"):
+            if skip_idle_prefill:
+                caches, _, last_tok, pos = jax.lax.cond(
+                    take.any(), do_prefill, lambda op: op, operand)
+            else:
+                caches, _, last_tok, pos = do_prefill(operand)
         gen_buf = jnp.where(take[:, None],
                             gen_buf.at[:, 0].set(last_tok), gen_buf)
         n_gen = jnp.where(take, 1, n_gen)
@@ -178,8 +188,9 @@ def make_engine_step(runner, *, num_slots: int, arrival_slots: int,
             busy = busy + active.sum().astype(jnp.float32)
             logits, caches = runner.decode(params, last_tok[:, None],
                                            caches, pos)
-            nxt = _row_sample(logits.astype(jnp.float32), base_key, req_id,
-                              n_gen, temperature)
+            with jax.named_scope("engine.sample"):
+                nxt = _row_sample(logits.astype(jnp.float32), base_key,
+                                  req_id, n_gen, temperature)
             last_tok = jnp.where(active, nxt, last_tok)
             written = jax.vmap(
                 lambda row, t, i: jax.lax.dynamic_update_slice(
@@ -193,8 +204,9 @@ def make_engine_step(runner, *, num_slots: int, arrival_slots: int,
 
         carry = (caches, last_tok, pos, n_gen, active, gen_buf,
                  state.busy_steps)
-        (caches, last_tok, pos, n_gen, active, gen_buf, busy), _ = (
-            jax.lax.scan(dstep, carry, None, length=decode_chunk))
+        with jax.named_scope("engine.decode"):
+            (caches, last_tok, pos, n_gen, active, gen_buf, busy), _ = (
+                jax.lax.scan(dstep, carry, None, length=decode_chunk))
 
         state = EngineState(
             caches=caches, prompt=prompt, plen=plen, gen_target=gen_target,

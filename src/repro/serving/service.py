@@ -8,6 +8,19 @@ buffers, (c) calls the engine step, and (d) drains completions from the
 small report readback (pulling ``gen_buf`` rows only for slots that
 finished). Idle ticks (nothing pending, nothing active) skip the step
 call entirely.
+
+A :class:`repro.tracing.Tracer` passed as ``tracer`` records each loop
+turn as a ``serve.tick`` span holding ``serve.admit`` (queue advance,
+expiry, packing and the arrival buffers' copies to the device),
+``serve.dispatch`` (the engine step's call, which returns once the step is
+queued), ``serve.readback`` (the host waits for the report),
+``serve.drain`` (completion bookkeeping), ``serve.sleep`` (idle wait) and
+``serve.fault`` (outage handling), and one ``serve.request`` event per
+completion with its ``rid`` and its ``arrival``, ``admit``,
+``first_token`` and ``done`` times. Like the spans, these are on
+``time.perf_counter``: ``arrival`` is the moment the service clock passed
+the request's arrival time (``run`` without ``realtime`` moves that clock
+ahead over idle stretches, so it may run ahead of ``perf_counter``).
 """
 from __future__ import annotations
 
@@ -19,6 +32,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.serving.config import ServeConfig
+from repro.tracing import NULL_TRACER
 
 
 @dataclass
@@ -44,11 +58,22 @@ class Completion:
     tokens: np.ndarray
     arrival_time: float
     admit_time: float
+    # end of the report readback of the admitting tick, whose prefill
+    # produced token 0
+    first_token_time: float
     done_time: float
 
     @property
     def latency(self) -> float:
         return self.done_time - self.arrival_time
+
+    @property
+    def ttft(self) -> float:
+        return self.first_token_time - self.arrival_time
+
+    @property
+    def queue_wait(self) -> float:
+        return self.admit_time - self.arrival_time
 
 
 class RequestQueue:
@@ -58,9 +83,14 @@ class RequestQueue:
         self._future = sorted(trace, key=lambda r: r.arrival_time)
         self._ready: deque = deque()
 
-    def advance(self, now: float) -> None:
+    def advance(self, now: float) -> List[Request]:
+        """Move requests whose arrival time has passed into the FIFO;
+        returns them."""
+        moved = []
         while self._future and self._future[0].arrival_time <= now:
-            self._ready.append(self._future.pop(0))
+            moved.append(self._future.pop(0))
+        self._ready.extend(moved)
+        return moved
 
     def pop(self, k: int) -> List[Request]:
         k = max(min(int(k), len(self._ready)), 0)  # k <= 0 pops nothing
@@ -135,7 +165,8 @@ class SlotScheduler:
 class ServingService:
     """The continuous-batching service loop over one engine."""
 
-    def __init__(self, cfg: ServeConfig, params=None, mesh=None):
+    def __init__(self, cfg: ServeConfig, params=None, mesh=None,
+                 tracer=NULL_TRACER):
         import jax
         import jax.numpy as jnp
 
@@ -144,6 +175,7 @@ class ServingService:
         from repro.serving.runners import PipelineRunner, SingleDeviceRunner
 
         self.cfg = cfg
+        self.tracer = tracer
         self.model_cfg = cfg.model_config()
         dtype = jnp.dtype(cfg.compute_dtype)
         if cfg.boundaries is None:
@@ -249,11 +281,18 @@ class ServingService:
                 return float(max(t, np.where(cov, f_end[f_stage],
                                              -np.inf).max()))
         admit_t: Dict[int, float] = {}
+        first_t: Dict[int, float] = {}
+        # for the tracer's serve.request events, on perf_counter: the
+        # service clock is perf_counter - t0, and t0 moves on idle jumps
+        # and fault stalls, so each time takes the t0 of its own moment
+        arrive_pc: Dict[int, float] = {}
+        admit_t0: Dict[int, float] = {}
         arrive_t = {r.rid: r.arrival_time for r in trace}
         completions: List[Completion] = []
         seen_done = set()
         inflight: Dict[int, Request] = {}
         expired: List[Request] = []
+        tr = self.tracer
         t0 = time.perf_counter()
         free = self.cfg.num_slots
         active_rids: set = set()
@@ -261,107 +300,143 @@ class ServingService:
         fault_events = retries = evictions = recovery_ticks = 0
         tick = 0
         while tick < max_ticks:
-            now = time.perf_counter() - t0
-            queue.advance(now)
-            expired.extend(queue.drop_expired(now))
-            if queue.pending == 0 and not active_rids:
-                if queue.exhausted:
-                    break
-                # idle: jump the virtual clock to the next arrival
-                nxt = queue.next_arrival()
-                if realtime:
-                    time.sleep(max(nxt - now, 0.0))
-                else:
-                    t0 -= max(nxt - now, 0.0)
-                queue.advance(time.perf_counter() - t0)
-                expired.extend(queue.drop_expired(time.perf_counter() - t0))
+            with tr.span("serve.tick", tick=tick) as tick_attrs:
+                with tr.span("serve.admit"):
+                    now = time.perf_counter() - t0
+                    for r in queue.advance(now):
+                        arrive_pc[r.rid] = t0 + r.arrival_time
+                    expired.extend(queue.drop_expired(now))
                 if queue.pending == 0 and not active_rids:
-                    # early wake / all arrivals expired: nothing to do,
-                    # skip the engine dispatch instead of burning a
-                    # no-op step (the realtime busy-loop fix)
-                    tick += 1
-                    continue
-            if faults is not None:
-                now = time.perf_counter() - t0
-                t_f = clock.time_of(tick, now)
-                up = _f_up(t_f)
-                down = [d for d in self.stage_devices if not up[d]]
-                if down:
-                    fault_events += 1
-                    # bounded exponential backoff before giving up
-                    t_probe, backoff = t_f, self.cfg.retry_backoff_s
-                    recovered = False
-                    for _ in range(max(self.cfg.max_retries, 0)):
-                        retries += 1
-                        t_probe += backoff
-                        backoff *= 2.0
-                        probe_up = _f_up(t_probe)
-                        if all(probe_up[d] for d in self.stage_devices):
-                            recovered = True
-                            break
-                    if not recovered:
-                        # give up on this outage: free every in-flight
-                        # slot (the pipeline spans all stage devices),
-                        # requeue its requests at the head, and route
-                        # re-planning around the dead devices
-                        victims = sorted(
-                            (inflight[r] for r in active_rids if r in inflight),
-                            key=lambda r: (r.arrival_time, r.rid))
-                        if victims:
-                            evictions += len(victims)
-                            queue.requeue_front(victims)
-                            self.state = evict_slots(
-                                self.state, np.asarray(self.state.active))
-                            active_rids = set()
-                            free = self.cfg.num_slots
-                        if self.replanner is not None:
-                            occupancy = 0.0
-                            replans.append(self.replanner.replan(
-                                load=occupancy, exclude_devices=down))
-                        t_probe = _f_recovery(t_probe)
-                    # stall to the recovery point: charge it to the
-                    # clock and advance the fault clock past it
-                    stall = max(t_probe - t_f, 0.0)
-                    if realtime:
-                        time.sleep(stall)
-                    else:
-                        t0 -= stall
-                    skipped = clock.ticks_until(t_f, t_probe)
-                    recovery_ticks += skipped
-                    tick += skipped
-                    continue
-            reqs, ap, al, ag, ar, n_arr = sched.pack(queue, free)
-            now = time.perf_counter() - t0
-            for r in reqs:
-                admit_t[r.rid] = now
-                inflight[r.rid] = r
-            self.state, report = self._jstep(
-                self.params, self.state, jnp.asarray(ap), jnp.asarray(al),
-                jnp.asarray(ag), jnp.asarray(ar), jnp.int32(n_arr))
-            act = np.asarray(report["active"])
-            rids = np.asarray(report["req_id"])
-            ngen = np.asarray(report["n_gen"])
-            now = time.perf_counter() - t0
-            active_rids = {int(r) for r, a in zip(rids, act) if a and r >= 0}
-            done_slots = [s for s in range(len(rids))
-                          if rids[s] >= 0 and not act[s]
-                          and int(rids[s]) not in seen_done]
-            if done_slots:
-                buf = np.asarray(self.state.gen_buf)  # pull only on completions
-                for s in done_slots:
-                    rid = int(rids[s])
-                    seen_done.add(rid)
-                    inflight.pop(rid, None)
-                    completions.append(Completion(
-                        rid=rid, tokens=buf[s, :ngen[s]].copy(),
-                        arrival_time=arrive_t[rid],
-                        admit_time=admit_t[rid], done_time=now))
-            free = int((~act).sum())
-            if (self.replanner is not None and self.cfg.replan_every
-                    and tick % self.cfg.replan_every == 0):
-                occupancy = float(act.sum()) / max(len(act), 1)
-                replans.append(self.replanner.replan(load=occupancy))
-            tick += 1
+                    if queue.exhausted:
+                        break
+                    # idle: jump the virtual clock to the next arrival
+                    with tr.span("serve.sleep"):
+                        nxt = queue.next_arrival()
+                        if realtime:
+                            time.sleep(max(nxt - now, 0.0))
+                        else:
+                            t0 -= max(nxt - now, 0.0)
+                        for r in queue.advance(time.perf_counter() - t0):
+                            arrive_pc[r.rid] = t0 + r.arrival_time
+                        expired.extend(
+                            queue.drop_expired(time.perf_counter() - t0))
+                    if queue.pending == 0 and not active_rids:
+                        # early wake / all arrivals expired: nothing to do,
+                        # skip the engine dispatch instead of burning a
+                        # no-op step (the realtime busy-loop fix)
+                        tick += 1
+                        continue
+                if faults is not None:
+                    now = time.perf_counter() - t0
+                    t_f = clock.time_of(tick, now)
+                    up = _f_up(t_f)
+                    down = [d for d in self.stage_devices if not up[d]]
+                    if down:
+                        with tr.span("serve.fault", down=down):
+                            fault_events += 1
+                            # bounded exponential backoff before giving up
+                            t_probe, backoff = t_f, self.cfg.retry_backoff_s
+                            recovered = False
+                            for _ in range(max(self.cfg.max_retries, 0)):
+                                retries += 1
+                                t_probe += backoff
+                                backoff *= 2.0
+                                probe_up = _f_up(t_probe)
+                                if all(probe_up[d]
+                                       for d in self.stage_devices):
+                                    recovered = True
+                                    break
+                            if not recovered:
+                                # give up on this outage: free every in-flight
+                                # slot (the pipeline spans all stage devices),
+                                # requeue its requests at the head, and route
+                                # re-planning around the dead devices
+                                victims = sorted(
+                                    (inflight[r] for r in active_rids
+                                     if r in inflight),
+                                    key=lambda r: (r.arrival_time, r.rid))
+                                if victims:
+                                    evictions += len(victims)
+                                    queue.requeue_front(victims)
+                                    self.state = evict_slots(
+                                        self.state,
+                                        np.asarray(self.state.active))
+                                    active_rids = set()
+                                    free = self.cfg.num_slots
+                                if self.replanner is not None:
+                                    occupancy = 0.0
+                                    replans.append(self.replanner.replan(
+                                        load=occupancy, exclude_devices=down))
+                                t_probe = _f_recovery(t_probe)
+                            # stall to the recovery point: charge it to the
+                            # clock and advance the fault clock past it
+                            stall = max(t_probe - t_f, 0.0)
+                            if realtime:
+                                time.sleep(stall)
+                            else:
+                                t0 -= stall
+                            skipped = clock.ticks_until(t_f, t_probe)
+                            recovery_ticks += skipped
+                        tick += skipped
+                        continue
+                if tr.enabled:
+                    tick_attrs.update(pending=queue.pending, free=free)
+                with tr.span("serve.admit"):
+                    reqs, ap, al, ag, ar, n_arr = sched.pack(queue, free)
+                    now = time.perf_counter() - t0
+                    for r in reqs:
+                        admit_t[r.rid] = now
+                        admit_t0[r.rid] = t0
+                        inflight[r.rid] = r
+                    arrivals = (jnp.asarray(ap), jnp.asarray(al),
+                                jnp.asarray(ag), jnp.asarray(ar),
+                                jnp.int32(n_arr))
+                with tr.span("serve.dispatch"):
+                    self.state, report = self._jstep(
+                        self.params, self.state, *arrivals)
+                with tr.span("serve.readback"):
+                    act = np.asarray(report["active"])
+                    rids = np.asarray(report["req_id"])
+                    ngen = np.asarray(report["n_gen"])
+                    now = time.perf_counter() - t0
+                with tr.span("serve.drain"):
+                    for r in reqs:  # prefill gave them token 0 this tick
+                        first_t[r.rid] = now
+                    active_rids = {int(r) for r, a in zip(rids, act)
+                                   if a and r >= 0}
+                    done_slots = [s for s in range(len(rids))
+                                  if rids[s] >= 0 and not act[s]
+                                  and int(rids[s]) not in seen_done]
+                    if done_slots:
+                        # pull only on completions
+                        buf = np.asarray(self.state.gen_buf)
+                        for s in done_slots:
+                            rid = int(rids[s])
+                            seen_done.add(rid)
+                            inflight.pop(rid, None)
+                            c = Completion(
+                                rid=rid, tokens=buf[s, :ngen[s]].copy(),
+                                arrival_time=arrive_t[rid],
+                                admit_time=admit_t[rid],
+                                first_token_time=first_t[rid], done_time=now)
+                            completions.append(c)
+                            if tr.enabled:
+                                base = admit_t0[rid]
+                                tr.event("serve.request", arrive_pc[rid],
+                                         t0 + now, rid=rid,
+                                         arrival=arrive_pc[rid],
+                                         admit=base + c.admit_time,
+                                         first_token=base + first_t[rid],
+                                         done=t0 + now)
+                    free = int((~act).sum())
+                if tr.enabled:
+                    tick_attrs.update(packed=[r.rid for r in reqs],
+                                      active_after=len(active_rids))
+                if (self.replanner is not None and self.cfg.replan_every
+                        and tick % self.cfg.replan_every == 0):
+                    occupancy = float(act.sum()) / max(len(act), 1)
+                    replans.append(self.replanner.replan(load=occupancy))
+                tick += 1
         wall = time.perf_counter() - t0
         return self._metrics(completions, wall, tick, replans,
                              expired=expired, fault_events=fault_events,
@@ -372,13 +447,17 @@ class ServingService:
                  ticks: int, replans, *, expired=(), fault_events: int = 0,
                  retries: int = 0, evictions: int = 0,
                  recovery_ticks: int = 0) -> Dict:
-        lats = sorted(c.latency for c in completions)
         total_tokens = int(sum(len(c.tokens) for c in completions))
         busy = float(self.state.busy_steps)
         steps = float(self.state.decode_steps)
-        # empty-trace runs report 0.0, not NaN (NaN poisons JSON gates)
-        pct = (lambda q: lats[min(int(q * len(lats)), len(lats) - 1)]
-               if lats else 0.0)
+
+        def pct(values, q):
+            # empty-trace runs report 0.0, not NaN (NaN poisons JSON gates)
+            xs = sorted(values)
+            return xs[min(int(q * len(xs)), len(xs) - 1)] if xs else 0.0
+
+        lats = [c.latency for c in completions]
+        ttft = [c.ttft for c in completions]
         return {
             "completions": {c.rid: c.tokens for c in completions},
             "latencies": {c.rid: c.latency for c in completions},
@@ -387,8 +466,13 @@ class ServingService:
             "ticks": ticks,
             "requests_per_sec": len(completions) / wall if wall else 0.0,
             "tokens_per_sec": total_tokens / wall if wall else 0.0,
-            "p50_latency_s": pct(0.50),
-            "p99_latency_s": pct(0.99),
+            "p50_latency_s": pct(lats, 0.50),
+            "p99_latency_s": pct(lats, 0.99),
+            # time to first token and the part of it spent queued
+            "ttft_p50_s": pct(ttft, 0.50),
+            "ttft_p95_s": pct(ttft, 0.95),
+            "queue_wait_p95_s": pct((c.queue_wait for c in completions),
+                                    0.95),
             # structural accounting (wall-clock independent, as in
             # core.transport): fraction of slot-steps doing useful decode
             "slot_occupancy": busy / (steps * self.cfg.num_slots)

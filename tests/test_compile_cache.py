@@ -63,3 +63,35 @@ print(REPO_CACHE_DIR)
     assert os.listdir(cache), "no cache entry written"
     assert out.strip() == REPO_CACHE
     assert _entries(REPO_CACHE) == before, "the repo cache was written"
+
+
+# one program whose named scope is {scope}; prints the scopes its
+# executable (compiled or loaded from the cache) carries and the hits
+_SCOPED = """
+import re, jax, jax.numpy as jnp, numpy as np
+from repro.launch.compile_cache import enable_compile_cache
+enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+hits = []
+jax.monitoring.register_event_listener(
+    lambda e, **k: hits.append(e) if e.endswith("cache_hits") else None)
+def f(x):
+    with jax.named_scope({scope!r}):
+        return jnp.sin(x) * 7.0
+text = jax.jit(f).lower(np.arange(8.0, dtype=np.float32)).compile().as_text()
+print(sorted(set(re.findall(r"engine[.][a-z]+", text))), len(hits))
+"""
+
+
+def test_cached_executables_keep_their_scopes(tmp_path):
+    """The same program run again loads its executable from the cache; the
+    program with only its named scope changed compiles anew, so a profile
+    never shows another version's scopes."""
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc")}
+    first = _run(_SCOPED.format(scope="engine.old"), **env).split("]")
+    again = _run(_SCOPED.format(scope="engine.old"), **env).split("]")
+    renamed = _run(_SCOPED.format(scope="engine.new"), **env).split("]")
+    assert first == ["['engine.old'", " 0\n"]
+    assert again == ["['engine.old'", " 1\n"]
+    assert renamed == ["['engine.new'", " 0\n"]
