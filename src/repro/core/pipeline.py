@@ -924,9 +924,9 @@ def pipeline_serve_fns(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                             (xc, k_i, v_i))
                     return (xc,), (k_i, v_i)
 
-                # as in models.model.forward: the scan's own slicing and
-                # stacking of the stage's cache is model.layers less
-                # model.block
+                # the scan's own slicing and stacking of the stage's
+                # cache is model.layers less model.block (models.model's
+                # forward carries its cache and writes it in place)
                 with jax.named_scope("model.layers"):
                     (xx,), (nk, nv) = jax.lax.scan(
                         body, (xx,), (stage_blocks, ck, cv, codes,

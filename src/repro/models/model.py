@@ -5,7 +5,10 @@ MLP presence) is derived from the config; layers are grouped into the
 smallest repeating period so ``jax.lax.scan`` keeps compile time O(period),
 not O(depth) - essential for 94-96 layer models on the dry-run host.
 
-KV/SSM caches are threaded through the same scan as stacked xs/ys.
+KV/SSM caches are stacked per slot (leading dim = repeats) and carried
+through the layer loop: each layer writes its new K/V rows (or SSM state)
+into the stack in place at its own index, so a decode step writes only
+the new positions and never slices out or restacks a layer's cache.
 """
 from __future__ import annotations
 
@@ -80,9 +83,15 @@ def block_apply(
     positions,
     cache=None,
     cache_index=None,
+    layer=None,
     impl: str = "auto",
 ):
-    """One residual block. Returns (x, new_cache, aux)."""
+    """One residual block. Returns (x, new_cache, aux).
+
+    ``cache`` is this layer's cache dict, or, with ``layer`` = r, its
+    slot's stacked dict as the layer loop carries it: layer r is updated
+    in place and ``new_cache`` is the whole stack.
+    """
     kind, is_moe, has_mlp = slot_sig
     aux = jnp.zeros((), jnp.float32)
     # "pallas_stage" (the split executor's PipelineConfig.stage_impl knob)
@@ -94,19 +103,26 @@ def block_apply(
         out, new_kv = L.attention_apply(
             p["attn"], h, cfg, positions=positions,
             kv_cache=None if cache is None else {"k": cache["k"], "v": cache["v"]},
-            cache_index=cache_index, impl=half_impl,
+            cache_index=cache_index, layer=layer, impl=half_impl,
         )
         new_cache = {} if new_kv is None else new_kv
     else:
+        state = cache
+        if cache is not None and layer is not None:
+            state = {n: jax.lax.dynamic_index_in_dim(c, layer, 0, False)
+                     for n, c in cache.items()}
         out, (new_ssm, new_conv) = S.mamba_apply(
             p["mamba"], h, cfg,
-            ssm_state=None if cache is None else cache["ssm"],
-            conv_state=None if cache is None else cache["conv"],
+            ssm_state=None if state is None else state["ssm"],
+            conv_state=None if state is None else state["conv"],
             use_pallas=(half_impl == "pallas"),
         )
-        new_cache = {"ssm": new_ssm, "conv": new_conv}
-        if cache is None:
-            new_cache = {}
+        new_cache = {}
+        if cache is not None:
+            new_cache = {"ssm": new_ssm, "conv": new_conv}
+            if layer is not None:
+                new_cache = {n: jax.lax.dynamic_update_index_in_dim(
+                    cache[n], c, layer, 0) for n, c in new_cache.items()}
     x = x + out
     if has_mlp:
         if is_moe:
@@ -242,24 +258,23 @@ def forward(
         positions = cache_index + jnp.arange(s)
 
     def body(carry, xs):
-        xact, aux = carry
-        slot_params, slot_caches = xs
+        # carry holds the stacked caches (None when uncached); ``r`` is the
+        # repeat index every slot's cache is written at
+        xact, aux, cc = carry
+        slot_params, r = xs
 
-        def inner(xact, aux, slot_params, slot_caches):
+        def inner(xact, aux, slot_params, cc):
             new_caches = []
             for si in range(period):
-                cache = None
-                if caches is not None:
-                    cache = slot_caches[si]
                 xact, nc, a = block_apply(
                     slot_params[si], xact, cfg, sig[si],
-                    positions=positions, cache=cache, cache_index=cache_index,
-                    impl=impl,
+                    positions=positions, cache=None if cc is None else cc[si],
+                    cache_index=cache_index, layer=r, impl=impl,
                 )
                 xact = constrain(xact, {0: "batch"})
                 new_caches.append(nc)
                 aux = aux + a
-            return xact, aux, tuple(new_caches)
+            return xact, aux, None if cc is None else tuple(new_caches)
 
         if remat:
             # NOTE: save_only_these_names("moe_a2a") was measured (SPerf
@@ -270,45 +285,28 @@ def forward(
         else:
             f = inner
         with jax.named_scope("model.block"):
-            xact, aux, new_caches = f(xact, aux, slot_params, slot_caches)
-        return (xact, aux), new_caches
+            xact, aux, cc = f(xact, aux, slot_params, cc)
+        return (xact, aux, cc), None
 
-    caches_xs = tuple({} for _ in range(period))
-    aux0 = jnp.zeros((), jnp.float32)
+    repeats = cfg.num_layers // period
+    carry = (x, jnp.zeros((), jnp.float32), caches)
     # the layer stack: what lies under model.layers and outside model.block
-    # is the scan's own work (slicing each layer's weights and cache out of
-    # the stacks, stacking the new caches), read by the benchmark
+    # is the loop's own work (slicing each layer's weights out of the
+    # stacks), read by the benchmark; the caches ride in the carry
     with jax.named_scope("model.layers"):
         if unroll:
             # python-loop unroll: true per-layer HLO (exact flop/collective
             # accounting in the dry-run; scan counts the body only once)
-            repeats = cfg.num_layers // period
-            carry = (x, aux0)
-            ys = []
             for r in range(repeats):
                 sp = jax.tree.map(lambda a: a[r], params["slots"])
-                cc = (jax.tree.map(lambda a: a[r], caches)
-                      if caches is not None else caches_xs)
-                carry, nc = body(carry, (sp, cc))
-                ys.append(nc)
-            (x, aux) = carry
-            if caches is None:
-                new_caches = None
-            else:
-                new_caches = jax.tree.map(lambda *zs: jnp.stack(zs), *ys)
+                carry, _ = body(carry, (sp, r))
         elif caches is None:
-            (x, aux), _ = jax.lax.scan(
-                lambda c, sp: body(c, (sp, caches_xs)),
-                (x, aux0),
-                params["slots"],
-            )
-            new_caches = None
+            carry, _ = jax.lax.scan(lambda c, sp: body(c, (sp, None)), carry,
+                                    params["slots"])
         else:
-            (x, aux), new_caches = jax.lax.scan(
-                body,
-                (x, aux0),
-                (params["slots"], caches),
-            )
+            carry, _ = jax.lax.scan(body, carry,
+                                    (params["slots"], jnp.arange(repeats)))
+    x, aux, new_caches = carry
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = (

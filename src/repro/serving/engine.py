@@ -16,8 +16,12 @@ audits this, same idiom as ``core.splitting.make_plan_scorer``).
 Named scopes (``jax.named_scope``, op metadata only) label the device
 time: ``engine.admit``, ``engine.prefill``, ``engine.decode`` and
 ``engine.sample`` (inside the other two); the model adds
-``model.layers`` (its layer scan), ``model.block`` (the scan's body) and
-``model.kv_write`` (its cache writes).
+``model.layers`` (its layer loop, which carries the stacked caches),
+``model.block`` (the loop's body) and ``model.kv_write`` (the in-place
+write of each layer's new K/V rows into the carried stack). The decode
+scan carries that same stack from step to step, so with the state
+donated a decode step writes one position per slot and layer in place
+and never slices out or restacks a layer's cache.
 
 Invariant the bit-identity proof leans on: KV caches only ever hold
 FINITE values. Freed slots are not zeroed - their stale rows are masked

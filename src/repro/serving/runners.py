@@ -10,7 +10,7 @@ jitted step:
 * ``decode(params, tok, caches, pos)``: ``tok`` (B, 1), ``pos`` (B,)
   per-slot entry counts -> ``(logits (B, V), new_caches)`` - one token
   per slot at each slot's OWN position (slot-indexed KV writes, see
-  ``models.layers._row_cache_update``).
+  ``models.layers.kv_write``).
 
 Both backends restrict to attention-only architectures (MoE allowed
 under DROPLESS dispatch): padded batched prefill relies on causal

@@ -44,12 +44,15 @@ def test_engine_step_scopes():
     assert any("engine.decode" in n and "engine.sample" in n for n in names)
     assert any("engine.prefill" in n and "model.kv_write" in n
                for n in names)
-    # the layer scan's own slicing and stacking of the cache: under
-    # model.layers, outside model.block
+    # the layer loop's own work, under model.layers and outside
+    # model.block, slices each layer's weights; the caches ride in its
+    # carry and are written in place under model.kv_write, never stacked
     own = {n.rsplit("/", 1)[1] for n in names
            if "engine.decode" in n and "model.layers" in n
            and "model.block" not in n}
-    assert {"dynamic_slice", "dynamic_update_slice"} <= own
+    assert "dynamic_slice" in own and "dynamic_update_slice" not in own
+    assert any("engine.decode" in n and "model.kv_write" in n
+               for n in names)
     # (a reduction's own computation carries a bare path: skip those)
     assert all("model.layers" in n for n in names
                if "model.block" in n and n.startswith("jit("))

@@ -362,3 +362,39 @@ print('MOE_SERVE_OK', len(cases))
 """,
         n_devices=2)
     assert "MOE_SERVE_OK 2" in out
+
+
+def test_decode_scan_does_not_copy_the_cache():
+    """Memory guard: the layer loop carries the stacked KV cache and
+    writes each token in place, so a jitted scan of decode steps with the
+    caches donated needs far less scratch than one copy of the cache
+    (slicing each layer out and restacking it took 1.5 copies here).
+    float32: the CPU compiler carries a bfloat16 loop state as float32,
+    which alone would read two copies whatever the model does."""
+    from dataclasses import replace
+    from repro.configs import get_config
+
+    cfg = replace(get_config("stablelm-1.6b").reduced(), num_layers=4,
+                  d_model=256, num_heads=8, num_kv_heads=8, head_dim=32)
+    runner = SingleDeviceRunner(cfg)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    slots, cache_len = 8, 256
+    caches = runner.init_caches(slots, cache_len)
+    cache_bytes = sum(a.nbytes for a in jax.tree.leaves(caches))
+    assert cache_bytes == 4 * slots * cache_len * 8 * 32 * 4 * 2
+
+    def run(params, caches, tok, pos):
+        def step(carry, _):
+            caches, pos = carry
+            logits, caches = runner.decode(params, tok, caches, pos)
+            return (caches, pos + 1), logits
+        (caches, _), logits = jax.lax.scan(step, (caches, pos), None,
+                                           length=4)
+        return caches, logits
+
+    tok = jnp.zeros((slots, 1), jnp.int32)
+    pos = jnp.arange(slots, dtype=jnp.int32) * 16
+    mem = jax.jit(run, donate_argnums=(1,)).lower(
+        params, caches, tok, pos).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5 * cache_bytes, (
+        mem.temp_size_in_bytes, cache_bytes)
