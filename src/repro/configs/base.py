@@ -21,6 +21,12 @@ class MoEConfig:
     expert_d_ff: int = 0          # per-expert FFN hidden size
     capacity_factor: float = 1.25
     router_aux_weight: float = 1e-2
+    # expert parallelism: this layer holds experts
+    # [expert_start, expert_start + num_held) of the num_experts the router
+    # scores (num_held 0: all of them) and returns only their part of the
+    # output; the other shares' parts are computed where they are held
+    num_held: int = 0
+    expert_start: int = 0
     # every `moe_every`-th block is MoE (1 = every block); used by hybrids
     moe_every: int = 1
     # token routing: "dropless" (sort-based grouping, every routed token
@@ -32,6 +38,11 @@ class MoEConfig:
     @property
     def enabled(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.num_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -62,6 +73,8 @@ class ModelConfig:
     head_dim: int = 0                 # 0 -> d_model // num_heads
     activation: str = "swiglu"        # swiglu | relu2 | gelu
     qkv_bias: bool = False
+    # per-head RMSNorm of q and k over head_dim, before RoPE (Qwen3)
+    qk_norm: bool = False
     rope_theta: float = 1e6
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
@@ -94,6 +107,14 @@ class ModelConfig:
         return self.moe.enabled and (i % max(self.moe.moe_every, 1) == 0)
 
     @property
+    def num_moe_layers(self) -> int:
+        """Layers with an expert FFN (what the router's aux loss averages
+        over)."""
+        return sum(self.is_moe_block(i) and (
+            self.pattern[i] == "A" or self.arch_type == "hybrid")
+            for i in range(self.num_layers))
+
+    @property
     def num_attn_layers(self) -> int:
         return self.pattern.count("A")
 
@@ -107,6 +128,8 @@ class ModelConfig:
         p = d * h * hd + 2 * d * kh * hd + h * hd * d
         if self.qkv_bias:
             p += (h + 2 * kh) * hd
+        if self.qk_norm:
+            p += 2 * hd
         return p
 
     def mlp_params(self, moe_block: bool) -> int:

@@ -1,4 +1,4 @@
-"""Qwen2.5-3B [hf:Qwen/Qwen2.5-3B family card].
+"""Qwen2.5-3B [https://huggingface.co/Qwen/Qwen2.5-3B/blob/main/config.json].
 
 36L d_model=2048 16H (GQA kv=2) d_ff=11008 vocab=151936, QKV bias.
 """
@@ -18,5 +18,5 @@ CONFIG = ModelConfig(
     qkv_bias=True,
     rope_theta=1e6,
     tie_embeddings=True,
-    source="hf:Qwen/Qwen2.5-0.5B",
+    source="https://huggingface.co/Qwen/Qwen2.5-3B/blob/main/config.json",
 )

@@ -335,7 +335,8 @@ def pipeline_loss_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
 
     ``env_axis``: on a 2-D (stage x env) mesh, shard the microbatch ROW
     dim over this axis (data parallelism composed with the pipeline);
-    the loss is ``pmean``-ed over it.
+    the loss is ``pmean``-ed over it. It leaves out the MoE router's aux
+    loss, which the 1F1B step adds.
     """
     sig = M.signature(cfg)
     period = M.find_period(sig)
@@ -469,6 +470,11 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
     * ``env_axis``: on a 2-D (stage x env) mesh, shard the microbatch ROW
       dim over this axis; loss and grads are ``pmean``-ed over it after
       the stage-axis reductions.
+    * a model with MoE blocks adds each microbatch's router aux loss
+      (``block_apply``'s ``stats["aux"]``, every stage's layers) to the
+      loss, and the step returns ``(loss, grads, moe_rows)``: ``moe_rows``
+      (L, held) int32, the rows each layer routed to each expert it holds,
+      summed over the step's microbatches (zeros for non-MoE layers).
     """
     if pipe.schedule == "fill_drain":
         loss_fn = pipeline_loss_fn(cfg, mesh, boundaries, n_microbatches,
@@ -484,6 +490,8 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
     period = M.find_period(sig)
     mixed = period > 1
     slot_sig = sig[0]
+    moe = any(is_moe for _, is_moe, _ in sig)
+    held = cfg.moe.held  # 0 for a dense model: its routed-row counts are empty
     uniq_keys = [_sig_field_keys(cfg, u) for u in uniq_sigs]
     s_stages = len(boundaries)
     lens = stage_lengths(boundaries)
@@ -533,60 +541,69 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
             is_last = sidx == s_stages - 1
             positions = jnp.arange(t_len)
 
+            def block_out(out, st):
+                # every block gives its aux loss and routed rows (0 and
+                # zeros where it has no experts)
+                return out, (st["aux"], st.get(
+                    "moe_rows", jnp.zeros((held,), jnp.int32)))
+
             if mixed:
                 # one switch branch per distinct signature; each reads ONLY
                 # its own fields of the union row, so the foreign zero-filled
-                # fields transpose to exact-zero gradients (MoE router aux is
-                # dropped, matching the homogeneous path)
+                # fields transpose to exact-zero gradients
                 branches = []
                 for u, keys in zip(uniq_sigs, uniq_keys):
                     def br(blk, xx, _u=u, _keys=keys):
                         sub = {k: blk[k] for k in _keys}
-                        out, _, _ = M.block_apply(
+                        out, _, st = M.block_apply(
                             sub, xx, cfg, _u, positions=positions,
                             cache=None, cache_index=None, impl=blk_impl,
                         )
-                        return out
+                        return block_out(out, st)
                     branches.append(br)
 
                 def apply_block(blk, code, xx):
                     return jax.lax.switch(code, branches, blk, xx)
             else:
                 def apply_block(blk, code, xx):
-                    out, _, _ = M.block_apply(
+                    out, _, st = M.block_apply(
                         blk, xx, cfg, slot_sig, positions=positions,
                         cache=None, cache_index=None, impl=blk_impl,
                     )
-                    return out
+                    return block_out(out, st)
 
             def stage_fwd(blocks, x):
+                """x -> (the stage's output, its aux loss, (max_len, held)
+                routed rows)."""
                 # scan over the padded block stack; the cond masks compute
                 # down to the stage's ACTIVE length (padding blocks are
                 # exact identities, so skipping them is value-preserving)
                 def body(xc, blk_code_i):
                     blk, code, i = blk_code_i
-                    xc = jax.lax.cond(
+                    return jax.lax.cond(
                         i < active_len,
                         lambda xx: apply_block(blk, code, xx),
-                        lambda xx: xx, xc)
-                    return xc, None
+                        lambda xx: (xx, (jnp.zeros((), jnp.float32),
+                                         jnp.zeros((held,), jnp.int32))),
+                        xc)
 
-                out, _ = jax.lax.scan(
+                out, st = jax.lax.scan(
                     body, x, (blocks, codes, jnp.arange(max_len)))
-                return out
+                return out, st[0].sum(), st[1]
 
             def stage_loss(blocks, fnorm, hd, x, lab):
-                y = stage_fwd(blocks, x)
+                y, aux, rows = stage_fwd(blocks, x)
                 with jax.named_scope("pipeline.head"):
                     xh = L.rms_norm(y, fnorm, cfg.norm_eps)
                     logits = jnp.einsum(head_spec, xh, hd.astype(y.dtype))
-                    return M.softmax_xent(logits, lab)
+                    loss = M.softmax_xent(logits, lab)
+                return loss + aux, rows
 
             perm_f = [(i, (i + 1) % s_stages) for i in range(s_stages)]
             perm_b = [(i, (i - 1) % s_stages) for i in range(s_stages)]
 
             def tick(carry, t):
-                # acc = (gblocks, gembed, gnorm, ghead, loss_acc)
+                # acc = (gblocks, gembed, gnorm, ghead, loss_acc, rows)
                 buf_x, buf_g, stash, acc = carry
 
                 # ---- the hops (Eq. 1 forward, Eq. 4 gradient) -------------
@@ -630,7 +647,7 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                     # so its forward slot only stashes
                     y = jax.lax.cond(
                         f_valid & (~is_last),
-                        lambda xx: stage_fwd(stage_blocks, xx),
+                        lambda xx: stage_fwd(stage_blocks, xx)[0],
                         lambda xx: xx,
                         x0,
                     )
@@ -651,31 +668,37 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 def run_bwd(operand):
                     x_sv, g, lb, acc = operand
 
+                    def seed():
+                        return jnp.asarray(1.0 / m_micro, jnp.float32)
+
                     def last_branch(acc):
-                        gblocks, gembed, gnorm, ghead, loss_acc = acc
-                        li, vjp = jax.vjp(
+                        gblocks, gembed, gnorm, ghead, loss_acc, racc = acc
+                        li, vjp, rows = jax.vjp(
                             lambda bl, fn_, hd_, xx: stage_loss(bl, fn_, hd_, xx, lb),
-                            stage_blocks, final_norm, head, x_sv,
+                            stage_blocks, final_norm, head, x_sv, has_aux=True,
                         )
-                        dbl, dfn, dhd, dx = vjp(jnp.asarray(1.0 / m_micro, jnp.float32))
+                        dbl, dfn, dhd, dx = vjp(seed())
                         with jax.named_scope("pipeline.accum"):
                             if tied:
                                 gembed = gembed + dhd
                             else:
                                 ghead = ghead + dhd
                             return (jax.tree.map(jnp.add, gblocks, dbl),
-                                    gembed, gnorm + dfn, ghead,
-                                    loss_acc + li), dx
+                                    gembed, gnorm + dfn, ghead, loss_acc + li,
+                                    racc + rows), dx
 
                     def mid_branch(acc):
-                        gblocks, gembed, gnorm, ghead, loss_acc = acc
-                        _, vjp = jax.vjp(
-                            lambda bl, xx: stage_fwd(bl, xx), stage_blocks, x_sv
-                        )
-                        dbl, dx = vjp(g)
+                        gblocks, gembed, gnorm, ghead, loss_acc, racc = acc
+                        # the stage's aux loss is part of the loss too
+                        (_, a), vjp, rows = jax.vjp(
+                            lambda bl, xx: (lambda o: (o[:2], o[2]))(
+                                stage_fwd(bl, xx)),
+                            stage_blocks, x_sv, has_aux=True)
+                        dbl, dx = vjp((g, seed()))
                         with jax.named_scope("pipeline.accum"):
                             return (jax.tree.map(jnp.add, gblocks, dbl),
-                                    gembed, gnorm, ghead, loss_acc), dx
+                                    gembed, gnorm, ghead, loss_acc + a,
+                                    racc + rows), dx
 
                     return jax.lax.cond(is_last, last_branch, mid_branch, acc)
 
@@ -687,7 +710,7 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                     acc, dx = jax.lax.cond(
                         b_valid, run_bwd, skip_bwd, (x_saved, g_in, lab, acc)
                     )
-                gblocks, gembed, gnorm, ghead, loss_acc = acc
+                gblocks, gembed, gnorm, ghead, loss_acc, racc = acc
                 # stage 0's dx is the cotangent of the embedding lookup;
                 # the full-vocab scatter-add is cond-gated like the other
                 # idle slots (it would otherwise run masked-to-zero on
@@ -699,7 +722,7 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                         lambda ge: ge,
                         gembed,
                     )
-                acc = (gblocks, gembed, gnorm, ghead, loss_acc)
+                acc = (gblocks, gembed, gnorm, ghead, loss_acc, racc)
 
                 if overlap:
                     # stage outputs become NEXT tick's in-flight buffers
@@ -725,11 +748,12 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 # tied: head grads go to gembed; a scalar placeholder here
                 jnp.zeros((), jnp.float32) if tied else jnp.zeros_like(head),
                 jnp.zeros((), jnp.float32),
+                jnp.zeros((max_len, held), jnp.int32),
             )
             (_, _, _, acc), _ = jax.lax.scan(
                 tick, (x0, g0, stash0, acc0), jnp.arange(n_ticks)
             )
-            gblocks, gembed, gnorm, ghead, loss_acc = acc
+            gblocks, gembed, gnorm, ghead, loss_acc, rows = acc
             loss = jax.lax.psum(loss_acc, stage_axis) / m_micro
             gembed = jax.lax.psum(gembed, stage_axis)
             gnorm = jax.lax.psum(gnorm, stage_axis)
@@ -742,11 +766,12 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 gembed = jax.lax.pmean(gembed, env_axis)
                 gnorm = jax.lax.pmean(gnorm, env_axis)
                 ghead = jax.lax.pmean(ghead, env_axis)
+                rows = jax.lax.psum(rows, env_axis)
             return (loss, jax.tree.map(lambda a: a[None], gblocks), gembed,
-                    gnorm, ghead)
+                    gnorm, ghead, rows[None])
 
         data_spec = P(None, env_axis) if env_axis is not None else P()
-        loss, gstages, gembed, gnorm, ghead = jax.shard_map(
+        loss, gstages, gembed, gnorm, ghead, rows = jax.shard_map(
             per_stage,
             mesh=mesh,
             in_specs=(
@@ -757,7 +782,7 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
             out_specs=(
                 P(),
                 jax.tree.map(lambda _: P(stage_axis), stage_blocks),
-                P(), P(), P(),
+                P(), P(), P(), P(stage_axis),
             ),
             check_vma=False,
         )(stage_blocks, codes_st, lens_arr, tok_mb, lab_mb, params["embed"],
@@ -773,7 +798,9 @@ def pipeline_step_fn(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
         grads["embed"] = gembed
         if not tied:
             grads["lm_head"] = ghead
-        return loss, grads
+        if not moe:  # callers of a dense step take (loss, grads)
+            return loss, grads
+        return loss, grads, unstack_stage_grads(rows, boundaries)
 
     return fn
 

@@ -1,4 +1,11 @@
-"""Pallas grouped expert-FFN kernel for dropless MoE dispatch.
+"""Grouped expert-FFN paths for dropless MoE dispatch.
+
+On a TPU ``layers.moe_dropless`` runs :func:`grouped_ffn_ragged`
+(``lax.ragged_dot`` over the expert-sorted rows, no padding). On other
+backends it runs :func:`grouped_ffn_reference` over a block-padded
+layout, and the Pallas kernel below is its CPU-tested fused variant
+(the TPU compiler refuses its ``(1,)`` expert-id block and whole-bank
+weight loads at published widths).
 
 ``layers.moe_apply_dropless`` sorts the (T*k) routed token copies by
 expert id and packs them into per-expert regions padded to ``blk``-row
@@ -75,6 +82,38 @@ def grouped_ffn_reference(buf, block_eid, w_gate, w_up, w_down,
     out = jnp.einsum("nbf,nfd->nbd", h, wd,
                      preferred_element_type=jnp.float32)
     return out.reshape(p, d).astype(dt)
+
+
+def grouped_ffn_ragged(rows, group_sizes, w_gate, w_up, w_down,
+                       activation: str):
+    """Expert FFN over expert-sorted rows with ``lax.ragged_dot``.
+
+    ``rows``: (R, D), the first ``group_sizes[0]`` rows belong to expert 0,
+    the next ``group_sizes[1]`` to expert 1, ...; rows past
+    ``sum(group_sizes)`` belong to no expert and come back as zeros. On a
+    TPU each product is one grouped-matmul kernel that reads each expert's
+    weights once and skips the row tiles past the groups, so its work
+    follows the routed rows, not ``R``; its gradient is a ragged product
+    too (no per-block weight copies). Returns (R, D) in the rows' dtype
+    (accumulation in float32).
+
+    A TPU kernel leaves the rows past the groups unwritten, in its output
+    and in its gradient for ``rows``: whatever memory held, NaN included.
+    So each product's output is masked to the grouped rows before it is
+    used, and the cotangent of ``rows`` is masked on the way back.
+    """
+    dt = rows.dtype
+    live = (jnp.arange(rows.shape[0]) < jnp.sum(group_sizes))[:, None]
+
+    def mm(a, w):
+        out = jax.lax.ragged_dot(a, w.astype(dt), group_sizes,
+                                 preferred_element_type=dt)
+        return jnp.where(live, out, 0)
+
+    rows = jnp.where(live, rows, 0)
+    g = mm(rows, w_gate) if activation == "swiglu" else None
+    h = _act(activation, g, mm(rows, w_up)).astype(dt)
+    return mm(h, w_down)
 
 
 def _kernel_gated(x_ref, eid_ref, wg_ref, wu_ref, wd_ref, out_ref, *,

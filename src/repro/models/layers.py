@@ -88,6 +88,9 @@ def init_attention(key, cfg: ModelConfig, dtype=jnp.float32):
         p["bq"] = jnp.zeros((h * hd,), dtype)
         p["bk"] = jnp.zeros((kh * hd,), dtype)
         p["bv"] = jnp.zeros((kh * hd,), dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.ones((hd,), dtype)
+        p["k_norm"] = jnp.ones((hd,), dtype)
     return p
 
 
@@ -256,7 +259,7 @@ def attention_apply(
     layer=None,
     impl: str = "auto",
 ):
-    """Self-attention with GQA + RoPE.
+    """Self-attention with GQA + RoPE (+ per-head QK-norm if configured).
 
     positions: (S,) absolute positions of the inputs, or (B, S) per-row
     positions when ``cache_index`` is a vector.
@@ -293,6 +296,11 @@ def attention_apply(
         kv_dim = 1
     k = constrain(k.reshape(b, s, kh, hd), {0: "batch", kv_dim: "model"})
     v = constrain(v.reshape(b, s, kh, hd), {0: "batch", kv_dim: "model"})
+    if cfg.qk_norm:
+        # every path (training, prefill, cached decode) normalises the
+        # fresh q and k here, so the cache holds normalised, rotated keys
+        q = rms_norm(q, params["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, params["k_norm"], cfg.norm_eps)
     cos, sin = rope_angles(positions, hd, cfg.rope_theta)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
@@ -433,16 +441,18 @@ def mlp_block(norm_w: Array, params, x: Array, activation: str,
 
 
 def init_moe(key, cfg: ModelConfig, dtype=jnp.float32):
+    """The router scores all ``num_experts``; the expert stacks hold only
+    this layer's share (``MoEConfig.held`` of them)."""
     m = cfg.moe
     ks = jax.random.split(key, 4)
-    d, f, e = cfg.d_model, m.expert_d_ff, m.num_experts
+    d, f, e, held = cfg.d_model, m.expert_d_ff, m.num_experts, m.held
     p = {
         "router": init_dense(ks[0], d, e, jnp.float32),
-        "w_up": (jax.random.normal(ks[2], (e, d, f)) / math.sqrt(d)).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], (e, f, d)) / math.sqrt(f)).astype(dtype),
+        "w_up": (jax.random.normal(ks[2], (held, d, f)) / math.sqrt(d)).astype(dtype),
+        "w_down": (jax.random.normal(ks[3], (held, f, d)) / math.sqrt(f)).astype(dtype),
     }
     if cfg.activation == "swiglu":
-        p["w_gate"] = (jax.random.normal(ks[1], (e, d, f)) / math.sqrt(d)).astype(dtype)
+        p["w_gate"] = (jax.random.normal(ks[1], (held, d, f)) / math.sqrt(d)).astype(dtype)
     return p
 
 
@@ -523,9 +533,16 @@ def moe_apply(params, x: Array, cfg: ModelConfig):
 def _moe_route(params, xt: Array, cfg: ModelConfig):
     """Shared token routing for the dropless + dense-reference paths.
 
-    xt: (T, D) flattened tokens. Returns (gates (T, k) f32 renormalized,
-    expert_ids (T, k) int32, aux scalar). Identical code on both sides is
-    what makes the dropless-vs-dense parity BITWISE rather than approximate.
+    xt: (T, D) flattened tokens. Returns (gates (T, k) f32 renormalized
+    over the k choices, expert_ids (T, k) int32 over ALL ``num_experts``,
+    aux scalar). Identical code on both sides is what makes the
+    dropless-vs-dense parity BITWISE rather than approximate.
+
+    ``aux`` is HF's ``load_balancing_loss_func`` over all k choices, ``E *
+    sum_e (tokens that chose e among their k, over T) * (mean router
+    probability of e)``, times ``router_aux_weight`` and over the model's
+    MoE layers, so that the layers' sum is the weighted mean. Every expert
+    share of a layer computes it alike from the whole router.
     """
     m = cfg.moe
     e, k = m.num_experts, m.top_k
@@ -533,10 +550,19 @@ def _moe_route(params, xt: Array, cfg: ModelConfig):
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, expert_ids = jax.lax.top_k(probs, k)  # (T, k)
     gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-    f_e = jnp.mean(jax.nn.one_hot(expert_ids[:, 0], e, dtype=jnp.float32), axis=0)
+    f_e = jnp.mean(jax.nn.one_hot(expert_ids, e, dtype=jnp.float32).sum(1),
+                   axis=0)
     p_e = jnp.mean(probs, axis=0)
-    aux = e * jnp.sum(f_e * p_e) * m.router_aux_weight
+    aux = (e * jnp.sum(f_e * p_e) * m.router_aux_weight
+           / max(cfg.num_moe_layers, 1))
     return gate_vals, expert_ids, aux
+
+
+def _held_choices(ids: Array, cfg: ModelConfig):
+    """(T, k) expert ids -> (T, k) index into this layer's held experts,
+    and whether each choice is held here."""
+    local = ids - cfg.moe.expert_start
+    return local, (local >= 0) & (local < cfg.moe.held)
 
 
 def _moe_combine(out_choices: Array, gates: Array, dtype) -> Array:
@@ -549,20 +575,22 @@ def _moe_combine(out_choices: Array, gates: Array, dtype) -> Array:
 
 
 def moe_apply_dense(params, x: Array, cfg: ModelConfig):
-    """Dense per-expert reference: EVERY expert FFN over EVERY token.
+    """Dense per-expert reference: EVERY held expert FFN over EVERY token.
 
     x: (B, S, D) -> (y, aux). O(T * E) FFN rows - the bitwise ground truth
     the dropless dispatch is parity-pinned against, never a production
     path. Written as a python loop over experts so each expert's rows go
-    through a plain (T, D) @ (D, F) gemm.
+    through a plain (T, D) @ (D, F) gemm. Choices of experts held
+    elsewhere (``MoEConfig.held`` < ``num_experts``) add nothing.
     """
     b, s, d = x.shape
-    e = cfg.moe.num_experts
+    m = cfg.moe
     xt = x.reshape(b * s, d)
     gates, ids, aux = _moe_route(params, xt, cfg)
+    local, mine = _held_choices(ids, cfg)
     swiglu = cfg.activation == "swiglu"
     per_expert = []
-    for j in range(e):
+    for j in range(m.held):
         wu = params["w_up"][j].astype(x.dtype)
         wd = params["w_down"][j].astype(x.dtype)
         if swiglu:
@@ -579,82 +607,156 @@ def moe_apply_dense(params, x: Array, cfg: ModelConfig):
         per_expert.append(
             jnp.einsum("tf,fd->td", h, wd,
                        preferred_element_type=jnp.float32).astype(x.dtype))
-    stacked = jnp.stack(per_expert)  # (E, T, D)
+    stacked = jnp.stack(per_expert)  # (E_held, T, D)
     t = b * s
-    got = stacked[ids, jnp.arange(t)[:, None]]  # (T, k, D)
+    got = stacked[jnp.clip(local, 0, m.held - 1), jnp.arange(t)[:, None]]
+    got = jnp.where(mine[..., None], got, 0)  # (T, k, D)
     y = _moe_combine(got, gates, x.dtype)
     return y.reshape(b, s, d), aux
 
 
+@jax.custom_vjp
+def _permute_rows(x: Array, perm: Array, inv: Array) -> Array:
+    """``x[perm]`` for a permutation ``perm`` of x's rows whose inverse is
+    ``inv``: the backward pass is the gather ``g[inv]``, not the
+    scatter-add a general gather transposes to."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inv):
+    return x[perm], (perm, inv)
+
+
+def _permute_rows_bwd(res, g):
+    perm, inv = res
+    return g[inv], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
 def moe_apply_dropless(params, x: Array, cfg: ModelConfig, *,
-                       impl: str = "reference", block_size: int = 128,
+                       impl: str = "auto", block_size: int = 128,
                        interpret=None):
+    """Dropless MoE dispatch; x: (B, S, D) -> (y, aux). See
+    :func:`moe_dropless`, which also returns the routed rows."""
+    y, aux, _ = moe_dropless(params, x, cfg, impl=impl, block_size=block_size,
+                             interpret=interpret)
+    return y, aux
+
+
+def moe_dropless(params, x: Array, cfg: ModelConfig, *, impl: str = "auto",
+                 block_size: int = 128, interpret=None):
     """Dropless MoE dispatch: sort-based token grouping + grouped matmul.
 
-    Every routed (token, choice) is computed - no capacity buffer, no
-    token dropping, so the output of a token is independent of which
-    other tokens share its dispatch group (the structural defect behind
-    the old ``jamba_decode`` xfail: the capacity path drops differently
-    at prefill group size vs decode group size 1).
+    Every routed (token, choice) whose expert is held here is computed -
+    no capacity buffer, no token dropping, so the output of a token is
+    independent of which other tokens share its dispatch group (the
+    structural defect behind the old ``jamba_decode`` xfail: the capacity
+    path drops differently at prefill group size vs decode group size 1).
+    The router scores all ``num_experts``; choices of experts held
+    elsewhere (``MoEConfig.held`` < ``num_experts``) sort after the held
+    ones and add nothing, so the shares of a layer sum to the whole layer.
 
-    x: (B, S, D) -> (y, aux). Stable-argsort the (T*k) flat expert ids,
-    gather tokens into expert-contiguous rows, run the expert FFN
-    grouped, then gather back through the inverse permutation and combine
-    with one einsum (order-preserving, see ``_moe_combine``).
+    x: (B, S, D) -> (y, aux, rows), ``rows`` (held,) int32 the routed rows
+    of each held expert. Stable-argsort the (T*k) flat expert ids, gather
+    tokens into expert-contiguous rows, run the expert FFN grouped, then
+    gather back through the inverse permutation and combine with one
+    einsum (order-preserving, see ``_moe_combine``).
 
-    Both impls share one padded layout: per-expert regions padded up to
-    ``block_size`` rows (a STATIC ``T*k + E*(block_size-1)`` row bound,
-    so the whole dispatch jits with fixed shapes; padding rows are zero
-    and never gathered back). impl="reference" runs the jittable
+    ``impl="auto"`` picks from the backend: on a TPU ``"ragged"``, the
+    ``lax.ragged_dot`` grouped matmul over the sorted rows (each held
+    expert's weights read once; the rows of other shares' choices lie past
+    the groups and are not computed), elsewhere ``"reference"``.
+    ``"reference"`` and ``"pallas"`` share one padded layout: per-expert
+    regions padded up to ``block_size`` rows (a STATIC
+    ``T*k + E*(block_size-1)`` row bound, so the whole dispatch jits with
+    fixed shapes; padding rows are zero and never gathered back).
+    ``"reference"`` runs the jittable
     ``kernels.moe_dispatch.grouped_ffn_reference`` batched einsum (the
-    production CPU path); impl="pallas" runs the fused
-    ``grouped_moe_ffn`` Pallas kernel over the same blocks. Both are
-    bitwise-identical to ``moe_apply_dense`` on CPU (pinned by
-    ``tests/test_moe_dropless.py``; ``lax.ragged_dot`` was rejected here
-    - its gemm blocking drifts ~2e-6 from the plain per-expert gemm).
+    CPU path); ``"pallas"`` runs the fused ``grouped_moe_ffn`` Pallas
+    kernel over the same blocks. Both are bitwise-identical to
+    ``moe_apply_dense`` on CPU (pinned by ``tests/test_moe_dropless.py``;
+    ``lax.ragged_dot`` is not: its gemm blocking drifts ~2e-6 from the
+    plain per-expert gemm).
+
+    Device scopes: ``model.moe`` holding ``model.moe.route`` (router,
+    top-k, sort and dispatch), ``model.moe.ffn`` (the grouped matmul) and
+    ``model.moe.combine``.
     """
     from repro.kernels.moe_dispatch import (
-        grouped_ffn_reference, grouped_moe_ffn,
+        grouped_ffn_ragged, grouped_ffn_reference, grouped_moe_ffn,
     )
 
     m = cfg.moe
     b, s, d = x.shape
-    e, k = m.num_experts, m.top_k
+    k, held = m.top_k, m.held
     t = b * s
-    xt = x.reshape(t, d)
-    gates, ids, aux = _moe_route(params, xt, cfg)
+    if impl == "auto":
+        impl = "ragged" if jax.default_backend() == "tpu" else "reference"
 
-    flat = ids.reshape(-1)                      # (T*k,) token-major
-    order = jnp.argsort(flat)                   # stable: ties keep token order
-    sorted_eids = flat[order]
-    counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+    def experts(w, xt, gates, mine, flat, order, inv, counts):
+        """Dispatch, grouped FFN and combine, given the routing."""
+        with jax.named_scope("model.moe.route"):
+            if impl == "ragged":
+                xs = _permute_rows(jnp.repeat(xt, k, axis=0), order, inv)
+            else:
+                sorted_eids = flat[order]
+                blk = block_size
+                padded = ((counts + blk - 1) // blk) * blk       # (held,)
+                starts = jnp.cumsum(padded) - padded
+                excl = jnp.cumsum(counts) - counts
+                pos_in_expert = jnp.arange(t * k) - excl[sorted_eids]
+                dest = starts[sorted_eids] + pos_in_expert          # unique rows
+                p_rows = -(-(t * k + held * (blk - 1)) // blk) * blk  # static
+                # other shares' rows are dropped
+                dest = jnp.where(sorted_eids < held, dest, p_rows)
+                pbuf = jnp.zeros((p_rows, d), x.dtype).at[dest].set(
+                    xt[order // k], mode="drop")
+                block_eid = jnp.minimum(
+                    jnp.searchsorted(jnp.cumsum(padded),
+                                     jnp.arange(p_rows // blk) * blk,
+                                     side="right"),
+                    held - 1).astype(jnp.int32)
 
-    blk = block_size
-    padded = ((counts + blk - 1) // blk) * blk              # (E,)
-    starts = jnp.cumsum(padded) - padded
-    excl = jnp.cumsum(counts) - counts
-    pos_in_expert = jnp.arange(t * k) - excl[sorted_eids]
-    dest = starts[sorted_eids] + pos_in_expert              # unique rows
-    p_rows = -(-(t * k + e * (blk - 1)) // blk) * blk       # static bound
-    pbuf = jnp.zeros((p_rows, d), x.dtype).at[dest].set(xt[order // k])
-    block_eid = jnp.minimum(
-        jnp.searchsorted(jnp.cumsum(padded),
-                         jnp.arange(p_rows // blk) * blk, side="right"),
-        e - 1).astype(jnp.int32)
+        with jax.named_scope("model.moe.ffn"):
+            if impl == "ragged":
+                out_sorted = grouped_ffn_ragged(
+                    xs, counts, w.get("w_gate"), w["w_up"], w["w_down"],
+                    cfg.activation)
+            elif impl == "reference":
+                out_p = grouped_ffn_reference(
+                    pbuf, block_eid, w.get("w_gate"), w["w_up"],
+                    w["w_down"], cfg.activation)
+            elif impl == "pallas":
+                out_p = grouped_moe_ffn(pbuf, block_eid, w,
+                                        activation=cfg.activation,
+                                        interpret=interpret)
+            else:
+                raise ValueError(f"unknown dropless impl {impl!r}")
 
-    if impl == "reference":
-        out_p = grouped_ffn_reference(
-            pbuf, block_eid, params.get("w_gate"), params["w_up"],
-            params["w_down"], cfg.activation)
-    elif impl == "pallas":
-        out_p = grouped_moe_ffn(pbuf, block_eid, params,
-                                activation=cfg.activation,
-                                interpret=interpret)
-    else:
-        raise ValueError(f"unknown dropless impl {impl!r}")
-    out_sorted = out_p[dest]
+        with jax.named_scope("model.moe.combine"):
+            if impl == "ragged":
+                got = _permute_rows(out_sorted, inv, order)
+            else:
+                got = out_p[dest][inv]
+            got = jnp.where(mine[..., None], got.reshape(t, k, d), 0)
+            return _moe_combine(got, gates, x.dtype)
 
-    inv = jnp.argsort(order)                    # flat choice -> sorted row
-    got = out_sorted[inv].reshape(t, k, d)
-    y = _moe_combine(got, gates, x.dtype)
-    return y.reshape(b, s, d), aux
+    with jax.named_scope("model.moe"):
+        with jax.named_scope("model.moe.route"):
+            xt = x.reshape(t, d)
+            gates, ids, aux = _moe_route(params, xt, cfg)
+            local, mine = _held_choices(ids, cfg)
+            # (T*k,) token-major; other shares' choices sort last
+            flat = jnp.where(mine, local, held).reshape(-1)
+            order = jnp.argsort(flat)           # stable: ties keep token order
+            inv = jnp.argsort(order)            # flat choice -> sorted row
+            # a sentinel id (held) one-hots to a zero row
+            counts = jnp.sum(jax.nn.one_hot(flat, held, dtype=jnp.int32), 0)
+        # the dispatched rows are k times the tokens: the backward pass
+        # recomputes them from the tokens and the routing, not keeps them
+        w = {n: params[n] for n in ("w_gate", "w_up", "w_down") if n in params}
+        y = jax.checkpoint(experts)(w, xt, gates, mine, flat, order, inv,
+                                    counts)
+    return y.reshape(b, s, d), aux, counts

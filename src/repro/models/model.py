@@ -86,14 +86,17 @@ def block_apply(
     layer=None,
     impl: str = "auto",
 ):
-    """One residual block. Returns (x, new_cache, aux).
+    """One residual block. Returns (x, new_cache, stats).
 
     ``cache`` is this layer's cache dict, or, with ``layer`` = r, its
     slot's stacked dict as the layer loop carries it: layer r is updated
-    in place and ``new_cache`` is the whole stack.
+    in place and ``new_cache`` is the whole stack. ``stats["aux"]`` is the
+    block's share of the auxiliary loss (the MoE router's; 0 elsewhere);
+    an MoE block's ``stats["moe_rows"]`` (held,) counts the rows routed to
+    each expert it holds.
     """
     kind, is_moe, has_mlp = slot_sig
-    aux = jnp.zeros((), jnp.float32)
+    stats = {"aux": jnp.zeros((), jnp.float32)}
     # "pallas_stage" (the split executor's PipelineConfig.stage_impl knob)
     # fuses the residual MLP half-block; the attention/mamba half keeps the
     # default routing.
@@ -130,12 +133,14 @@ def block_apply(
             from repro.models.moe_a2a import a2a_applicable, moe_apply_a2a
 
             h2 = L.rms_norm(x, p["norm2"], cfg.norm_eps)
+            rows = jnp.zeros((cfg.moe.held,), jnp.int32)
             if moe_a2a_enabled() and a2a_applicable(cfg):
                 y, aux = moe_apply_a2a(p["moe"], h2, cfg)
             elif cfg.moe.dispatch == "dropless":
-                y, aux = L.moe_apply_dropless(p["moe"], h2, cfg)
+                y, aux, rows = L.moe_dropless(p["moe"], h2, cfg)
             else:
                 y, aux = L.moe_apply(p["moe"], h2, cfg)
+            stats = {"aux": aux, "moe_rows": rows}
             x = x + y
         elif impl == "pallas_stage":
             from repro.kernels.stage_block import stage_mlp_block
@@ -145,7 +150,7 @@ def block_apply(
         else:
             x = L.mlp_block(p["norm2"], p["mlp"], x, cfg.activation,
                             cfg.norm_eps)
-    return x, new_cache, aux
+    return x, new_cache, stats
 
 
 # ---------------------------------------------------------------------------
@@ -266,14 +271,14 @@ def forward(
         def inner(xact, aux, slot_params, cc):
             new_caches = []
             for si in range(period):
-                xact, nc, a = block_apply(
+                xact, nc, st = block_apply(
                     slot_params[si], xact, cfg, sig[si],
                     positions=positions, cache=None if cc is None else cc[si],
                     cache_index=cache_index, layer=r, impl=impl,
                 )
                 xact = constrain(xact, {0: "batch"})
                 new_caches.append(nc)
-                aux = aux + a
+                aux = aux + st["aux"]
             return xact, aux, None if cc is None else tuple(new_caches)
 
         if remat:

@@ -4,9 +4,12 @@
     with tracer.span("serve.tick", tick=3) as attrs:
         ...
         attrs["active_after"] = 5       # attrs may be filled in late
+    tracer.count("moe.rows", [812, 790], step=7)   # a counter reading
     tracer.dump("trace.jsonl")          # one JSON object per line
     tracer.close()
 
+A counter reading is one event ``{name, t, value, id, parent, **attrs}``
+(``t`` on ``time.perf_counter``, ``value`` a number or a list of them).
 A span is one event ``{name, t0, t1, id, parent, **attrs}`` on
 ``time.perf_counter``, recorded when it closes. Each span also enters a
 ``jax.profiler.TraceAnnotation`` of its name, which puts it on the
@@ -75,6 +78,12 @@ class Tracer:
         self._events.append(dict(attrs, name=name, t0=t0, t1=t1,
                                  id=next(self._ids), parent=self._parent()))
 
+    def count(self, name: str, value, **attrs) -> None:
+        """A counter reading, as a child of the span open now."""
+        self._events.append(dict(attrs, name=name, t=time.perf_counter(),
+                                 value=value, id=next(self._ids),
+                                 parent=self._parent()))
+
     def events(self) -> list[dict]:
         return list(self._events)
 
@@ -129,6 +138,9 @@ class _NullTracer:
         return self._ctx
 
     def event(self, name, t0, t1, **attrs) -> None:
+        pass
+
+    def count(self, name, value, **attrs) -> None:
         pass
 
     def events(self) -> list:

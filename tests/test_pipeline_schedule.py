@@ -227,8 +227,10 @@ print('F1B_8STAGE_OK', float(l0))
 def test_1f1b_mixed_blocks_matches_forward_single_stage(arch):
     """Mixed block types per stage (PR 9): the union-param + lax.switch
     executor on a hybrid SSM/MoE (period-2) and pure-MoE stack must
-    match jax.value_and_grad of the plain forward pass - exact-zero
-    union rows for foreign fields must contribute exact-zero grads."""
+    match jax.value_and_grad of the plain forward pass, router aux loss
+    included, microbatch by microbatch - exact-zero union rows for foreign
+    fields must contribute exact-zero grads. The step's routed-row
+    counter holds every (token, choice) of each MoE layer."""
     from repro.models import model as M
 
     cfg = get_config(arch).reduced()
@@ -237,23 +239,30 @@ def test_1f1b_mixed_blocks_matches_forward_single_stage(arch):
     tokens, labels = _data(cfg, rows=2, seq=16)
 
     def ref_loss(p):
-        logits, _, _ = M.forward(p, tokens, cfg, compute_dtype=jnp.float32,
-                                 remat=False)
-        return M.softmax_xent(logits, labels)
+        # mean over the 2 one-row microbatches of cross-entropy + aux
+        def one(tok, lab):
+            logits, _, aux = M.forward(p, tok[None], cfg,
+                                       compute_dtype=jnp.float32, remat=False)
+            return M.softmax_xent(logits, lab[None]) + aux
+        return (one(tokens[0], labels[0]) + one(tokens[1], labels[1])) / 2
 
     l0, g0 = jax.jit(jax.value_and_grad(ref_loss))(params)
     f1 = pipeline_step_fn(cfg, mesh, (cfg.num_layers,), 2,
                           pipe=PipelineConfig(compute_dtype="float32"))
-    l1, g1 = jax.jit(f1)(params, tokens, labels)
+    l1, g1, rows = jax.jit(f1)(params, tokens, labels)
     np.testing.assert_allclose(float(l1), float(l0), rtol=RTOL)
     _assert_grads_close(g0, g1)
+    moe_layers = [is_moe for _, is_moe, _ in M.signature(cfg)]
+    np.testing.assert_array_equal(
+        np.asarray(rows).sum(1),
+        [tokens.size * cfg.moe.top_k * m for m in moe_layers])
 
 
 def test_1f1b_mixed_blocks_multistage(subproc):
     """Hybrid period-2 stack split unevenly across a real 2-stage mesh:
     the static per-slot block-kind schedule rides the shard_map scan
     (codes restacked like the union params) and must reproduce the plain
-    forward loss/grads."""
+    forward loss/grads, router aux loss included, per microbatch."""
     out = subproc(
         """
 import jax, jax.numpy as jnp, numpy as np
@@ -272,14 +281,20 @@ tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 16)), jnp.int32)
 labels = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 16)), jnp.int32)
 
 def ref_loss(p):
-    logits, _, _ = M.forward(p, tokens, cfg, compute_dtype=jnp.float32,
-                             remat=False)
-    return M.softmax_xent(logits, labels)
+    # mean over the 2 two-row microbatches of cross-entropy + aux
+    total = 0.0
+    for m in range(2):
+        logits, _, aux = M.forward(p, tokens[2 * m:2 * m + 2], cfg,
+                                   compute_dtype=jnp.float32, remat=False)
+        total = total + M.softmax_xent(logits, labels[2 * m:2 * m + 2]) + aux
+    return total / 2
 
 l0, g0 = jax.jit(jax.value_and_grad(ref_loss))(params)
 f1 = pipeline_step_fn(cfg, mesh, (1, 4), 2,  # uneven: stage lens 1/3
                       pipe=PipelineConfig(compute_dtype='float32'))
-l1, g1 = jax.jit(f1)(params, tokens, labels)
+l1, g1, rows = jax.jit(f1)(params, tokens, labels)
+assert np.asarray(rows).sum(1).tolist() == [
+    tokens.size * cfg.moe.top_k * m for _, m, _ in M.signature(cfg)], rows
 assert abs(float(l0) - float(l1)) <= 2e-5 * abs(float(l0)), (float(l0), float(l1))
 for (path, a), (_, b) in zip(jax.tree_util.tree_flatten_with_path(g0)[0],
                              jax.tree_util.tree_flatten_with_path(g1)[0]):

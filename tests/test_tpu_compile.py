@@ -144,3 +144,33 @@ def test_serving_engine_step_compiles_at_qwen_widths(one_chip):
     compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
     assert len(step.trace_count) == 1
     _fits(compiled)
+
+
+def test_moe_expert_share_compiles_at_qwen3_widths(one_chip):
+    """One Qwen3-MoE expert layer's forward and backward on one described
+    chip at published widths (8,192 tokens, 128 experts routed top-8, 16
+    held, expert width 768), through the chip's path (``impl="ragged"``:
+    the backend here is the CPU): grouped-matmul kernels, and no per-block
+    copy of the expert weights."""
+    import re
+
+    from repro.configs import get_config
+    from repro.models import layers as L
+
+    base = get_config("qwen3-moe-30b-a3b")
+    cfg = replace(base, moe=replace(base.moe, num_held=16))
+    params = _placed(jax.eval_shape(
+        lambda: L.init_moe(jax.random.PRNGKey(0), cfg)), one_chip)
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, xx):
+        y, aux, _ = L.moe_dropless(p, xx, cfg, impl="ragged")
+        return jnp.sum(y.astype(jnp.float32) ** 2) + aux
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert not re.search(r"\[\d+,2048,768\][^\n]* gather\(", text)
+    _fits(compiled)

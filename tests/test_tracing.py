@@ -132,3 +132,21 @@ def test_compile_spans():
     for e in tr.events():
         if e["name"] == "jax.compile":
             assert 0 <= e["t1"] - e["t0"] < 60
+
+
+def test_counter_readings_nest_and_dump(tmp_path):
+    with Tracer() as tr:
+        with tr.span("bench.wait", index=3):
+            tr.count("moe.rows", [812, 790], step=3, largest=[70, 66])
+        tr.count("moe.rows", [800, 801], step=4, largest=[64, 69])
+    NULL_TRACER.count("moe.rows", [1], step=0)
+    ev = [e for e in tr.events() if e["name"] == "moe.rows"]
+    wait = next(e for e in tr.events() if e["name"] == "bench.wait")
+    assert [e["value"] for e in ev] == [[812, 790], [800, 801]]
+    assert ev[0]["parent"] == wait["id"] and ev[1]["parent"] is None
+    assert ev[0]["largest"] == [70, 66] and ev[0]["t"] <= ev[1]["t"]
+    path = tmp_path / "trace.jsonl"
+    tr.dump(path)
+    lines = [json.loads(x) for x in path.read_text().splitlines()]
+    assert [x["step"] for x in lines if x["name"] == "moe.rows"] == [3, 4]
+    assert NULL_TRACER.events() == []
