@@ -841,11 +841,10 @@ def pipeline_serve_fns(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
 
     Returns ``(prefill, decode)`` with the engine's runner signatures:
 
-    * ``prefill(params, caches, prompts)``: ``prompts`` (B, P) ->
-      ``(logits (B, P, V), caches)`` - a fresh-sequence pass (scalar
-      cache index 0) through all stages; the caller gathers the row it
-      wants (per-slot prompt length) and WHERE-merges caches for the
-      slots it actually admitted.
+    * ``prefill(params, caches, prompts, last=None)``: ``prompts`` (B, P)
+      -> ``(logits (B, P, V), caches)`` - a fresh-sequence pass (scalar
+      cache index 0) through all stages; with ``last`` (B,) positions
+      only those rows reach the head and the logits are (B, V).
     * ``decode(params, tok, caches, pos)``: ``tok`` (B, 1), ``pos`` (B,)
       per-slot entry counts -> ``(logits (B, V), caches)`` - one token
       through the token ring.
@@ -886,10 +885,12 @@ def pipeline_serve_fns(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
     wdtype = pipe.wire
     perm_f = [(i, (i + 1) % s_stages) for i in range(s_stages)]
 
-    def _ring_pass(params, caches, x, positions, cache_index):
+    def _ring_pass(params, caches, x, positions, cache_index, last=None):
         """Token-ring forward: x (B, s, d) embedded input (live on stage 0).
 
-        Returns (logits (B, s, V), caches). Runs under shard_map."""
+        Returns (logits (B, s, V), caches), or with ``last`` (B,) positions
+        (logits (B, V), caches) with only those rows through the head.
+        Runs under shard_map."""
         if mixed:
             layer_stack = union_layer_params(params["slots"], cfg.num_layers)
         else:
@@ -899,8 +900,12 @@ def pipeline_serve_fns(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
         lens_arr = jnp.asarray(lens, jnp.int32)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
 
-        def per_stage(stage_blocks, codes_st, lens_arr, ck, cv, x, embed,
-                      final_norm, head):
+        gather = last is not None
+        if not gather:
+            last = jnp.zeros((x.shape[0],), jnp.int32)
+
+        def per_stage(stage_blocks, codes_st, lens_arr, ck, cv, x, last,
+                      embed, final_norm, head):
             stage_blocks = jax.tree.map(lambda a: a[0], stage_blocks)
             codes = codes_st[0]
             ck, cv = ck[0], cv[0]  # (max_len, B, kv, KH, hd)
@@ -969,6 +974,8 @@ def pipeline_serve_fns(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
                 x, ck, cv = jax.lax.cond(
                     sidx == t, stage_apply, lambda op: op, (x, ck, cv))
 
+            if gather:
+                x = jnp.take_along_axis(x, last[:, None, None], axis=1)
             xh = L.rms_norm(x, final_norm, cfg.norm_eps)
             logits = jnp.einsum("bsd,dv->bsv", xh, head.astype(x.dtype))
             is_last = (sidx == s_stages - 1)
@@ -983,19 +990,21 @@ def pipeline_serve_fns(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
             in_specs=(
                 jax.tree.map(lambda _: P(stage_axis), stage_blocks),
                 P(stage_axis), P(stage_axis), P(stage_axis), P(stage_axis),
-                P(), P(), P(), P(),
+                P(), P(), P(), P(), P(),
             ),
             out_specs=(P(), P(stage_axis), P(stage_axis)),
             check_vma=False,
         )(stage_blocks, codes_st, lens_arr, caches["k"], caches["v"], x,
-          params["embed"], params["final_norm"], head)
+          last, params["embed"], params["final_norm"], head)
+        if gather:
+            logits = logits[:, 0]
         return logits, {"k": ck, "v": cv}
 
-    def prefill(params, caches, prompts):
+    def prefill(params, caches, prompts, last=None):
         x = params["embed"].astype(pipe.dtype)[prompts]
         positions = jnp.arange(prompts.shape[1])
         return _ring_pass(params, caches, x, positions,
-                          jnp.zeros((), jnp.int32))
+                          jnp.zeros((), jnp.int32), last)
 
     def decode(params, tok, caches, pos):
         x = params["embed"].astype(pipe.dtype)[tok]

@@ -239,10 +239,14 @@ def forward(
     remat: bool = False,
     unroll: bool = False,
     compute_dtype=jnp.bfloat16,
+    last: Optional[Array] = None,
 ):
     """tokens: (B, S) int32. Returns (logits, new_caches, aux_loss).
 
     frontend_feats: (B, F, d_frontend) stub modality embeddings, prepended.
+    last: optional (B,) int32 positions; then only row ``last[b]`` of each
+    sequence reaches the final norm and the head, and the logits are
+    (B, V) instead of (B, S, V).
     """
     sig = signature(cfg)
     period = find_period(sig)
@@ -313,12 +317,16 @@ def forward(
                                     (params["slots"], jnp.arange(repeats)))
     x, aux, new_caches = carry
 
+    if last is not None:
+        x = jnp.take_along_axis(x, last[:, None, None], axis=1)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = (
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     ).astype(compute_dtype)
     logits = jnp.einsum("bsd,dv->bsv", x, head)
     logits = constrain(logits, {0: "batch", 2: "model"})
+    if last is not None:
+        logits = logits[:, 0]
     return logits, new_caches, aux
 
 

@@ -1,4 +1,4 @@
-"""Static batched generation: padded prefill + ONE fused decode dispatch.
+"""Static batched generation: one prefill + ONE fused decode dispatch.
 
 This is the shared core the v0 ``examples/serve.py`` and
 ``launch/serve.py`` both hand-rolled as a per-token Python loop (one
@@ -8,13 +8,20 @@ tokens - and the same routine serves as (a) the demo/launcher generate,
 (b) the benchmarks' static-batch baseline, and (c) the engine's
 bit-identity reference (``generate_reference``).
 
+The prefill is the engine's (``runners.prefill_rows``/``write_rows``): a
+buffer of prompt rows cut to a length class, run on a scratch cache with
+the head on each row's last position, then written into the decode rows.
+
 Bit-identity mechanics (measured on the CPU backend, pinned by
 ``tests/test_serving.py``): per-ROW float results are invariant to the
-other rows' contents at a FIXED batch shape, but a (1,d)x(d,e) decode
-matmul is NOT bitwise a row of the (N,d)x(d,e) one (gemv vs gemm
-accumulation order, ~6e-7 drift). ``generate_reference`` therefore runs
-the single request alone in row 0 of a batch PADDED to the engine's slot
-count - same shapes as the engine step, so equality is structural.
+other rows' contents at a FIXED batch shape, but not across shapes: a
+(1,d)x(d,e) decode matmul is NOT bitwise a row of the (N,d)x(d,e) one
+(gemv vs gemm accumulation order, ~6e-7 drift), and a prefill cut to 4
+positions is not bitwise the first rows of one cut to 8.
+``generate_reference`` therefore prefills the single request alone in
+row 0 of ``arrival_slots`` rows at the engine's length class and decodes
+it in row 0 of a batch PADDED to the engine's slot count - the engine
+step's shapes, so equality is structural.
 """
 from __future__ import annotations
 
@@ -22,6 +29,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.serving.runners import prefill_class, prefill_lengths, \
+    prefill_rows, write_rows
 
 Array = jax.Array
 
@@ -63,15 +73,52 @@ def _row_sample(logits: Array, base_key: Array, req_id: Array, token_idx,
         keys, logits.astype(jnp.float32) / temperature).astype(jnp.int32)
 
 
+def _prefill(runner, params, caches, prompts, plens, req_ids, base_key,
+             temperature):
+    """Prefill the first ``R`` of ``B`` decode rows from ``prompts``
+    (R, L), as the engine admits a tick's arrivals; rows past ``B`` are
+    inert. Returns (token 0 per decode row, caches)."""
+    r, b = prompts.shape[0], plens.shape[0]
+    k = min(r, b)
+    taken = jnp.arange(r) < k
+    pre_plen = jnp.ones((r,), jnp.int32).at[:k].set(plens[:k])
+    pre_req = jnp.full((r,), -1, jnp.int32).at[:k].set(req_ids[:k])
+    logits, fresh = prefill_rows(runner, params, caches, prompts, pre_plen)
+    caches = write_rows(caches, fresh, jnp.minimum(jnp.arange(r), b - 1),
+                        taken)
+    tok = _row_sample(logits.astype(jnp.float32), base_key, pre_req,
+                      jnp.zeros((r,), jnp.int32), temperature)
+    return jnp.zeros((b,), jnp.int32).at[:k].set(tok[:k]), caches
+
+
+def _prefill_buffer(prompts, plens, rows: Optional[int] = None,
+                   length: Optional[int] = None):
+    """The engine's prefill buffer for a static batch: ``prompts`` (b, P)
+    cut to ``length`` (default: the smallest class of
+    ``runners.prefill_lengths(P)`` that holds the longest prompt) and
+    zero-padded to ``rows`` rows (default ``b``)."""
+    prompts = jnp.asarray(prompts, jnp.int32)
+    b, p = prompts.shape
+    if length is None:
+        length = prefill_lengths(p)[prefill_class(p, int(jnp.max(plens)))]
+    rows = b if rows is None else rows
+    keep = min(length, p)
+    return jnp.zeros((rows, length), jnp.int32).at[:b, :keep].set(
+        prompts[:, :keep])
+
+
 def make_generate_fn(runner, *, max_new: int, temperature: float = 0.0):
     """Build the fused static generate: ONE prefill + ONE decode scan.
 
     Returned ``generate(params, caches, prompts, plens, gen_targets,
     req_ids, base_key)``:
 
-    * ``prompts`` (B, P) zero-padded, ``plens`` (B,) true lengths;
-    * ``gen_targets`` (B,) tokens wanted per row (<= max_new); rows stop
-      advancing once done (their KV writes freeze in place, masked);
+    * ``prompts`` (R, L): the prefill buffer (:func:`_prefill_buffer`),
+      row ``i`` the zero-padded prompt of decode row ``i``; decode rows
+      past ``R`` are inert padding;
+    * ``plens`` (B,) true lengths, ``gen_targets`` (B,) tokens wanted per
+      row (<= max_new); rows stop advancing once done (their KV writes
+      freeze in place, masked);
     * returns ``(tokens (B, max_new) int32, n_gen (B,))``.
 
     Jit this (it is one trace for all call sites); the decode scan is the
@@ -80,12 +127,9 @@ def make_generate_fn(runner, *, max_new: int, temperature: float = 0.0):
 
     def generate(params, caches, prompts, plens, gen_targets, req_ids,
                  base_key):
-        b = prompts.shape[0]
-        logits_all, caches = runner.prefill(params, caches, prompts)
-        last = jnp.take_along_axis(
-            logits_all, (plens - 1)[:, None, None], axis=1)[:, 0]
-        tok = _row_sample(last.astype(jnp.float32), base_key, req_ids,
-                          jnp.zeros((b,), jnp.int32), temperature)
+        b = plens.shape[0]
+        tok, caches = _prefill(runner, params, caches, prompts, plens,
+                               req_ids, base_key, temperature)
         buf0 = jnp.zeros((b, max_new), jnp.int32).at[:, 0].set(tok)
         active0 = gen_targets > 1
 
@@ -117,17 +161,23 @@ def make_generate_fn(runner, *, max_new: int, temperature: float = 0.0):
 def generate_static(runner, params, prompts, plens, gen_targets, *,
                     max_new: int, temperature: float = 0.0,
                     base_key=None, req_ids=None, cache_len=None,
-                    pad_rows: Optional[int] = None):
+                    pad_rows: Optional[int] = None,
+                    arrival_slots: Optional[int] = None,
+                    prefill_len: Optional[int] = None):
     """Convenience one-shot static generate (builds caches, jits, runs).
 
     ``pad_rows``: pad the batch with inert rows up to this total so the
     decode matmuls have the same shape as an engine with that many slots
     (see module docstring); returns only the real rows.
+    ``arrival_slots`` / ``prefill_len``: the prefill buffer's rows and
+    length (:func:`_prefill_buffer`), as an engine with that many arrival
+    slots prefills a tick at that length class.
     """
     prompts = jnp.asarray(prompts, jnp.int32)
     plens = jnp.asarray(plens, jnp.int32)
     gen_targets = jnp.asarray(gen_targets, jnp.int32)
     b, p = prompts.shape
+    pre = _prefill_buffer(prompts, plens, arrival_slots, prefill_len)
     if req_ids is None:
         req_ids = jnp.arange(b, dtype=jnp.int32)
     req_ids = jnp.asarray(req_ids, jnp.int32)
@@ -136,8 +186,6 @@ def generate_static(runner, params, prompts, plens, gen_targets, *,
     n_real = b
     if pad_rows is not None and pad_rows > b:
         pad = pad_rows - b
-        prompts = jnp.concatenate(
-            [prompts, jnp.zeros((pad, p), jnp.int32)])
         plens = jnp.concatenate([plens, jnp.ones((pad,), jnp.int32)])
         gen_targets = jnp.concatenate(
             [gen_targets, jnp.ones((pad,), jnp.int32)])
@@ -149,18 +197,22 @@ def generate_static(runner, params, prompts, plens, gen_targets, *,
     caches = runner.init_caches(b, cache_len)
     gen = jax.jit(make_generate_fn(runner, max_new=max_new,
                                    temperature=temperature))
-    buf, n_gen = gen(params, caches, prompts, plens, gen_targets, req_ids,
+    buf, n_gen = gen(params, caches, pre, plens, gen_targets, req_ids,
                      base_key)
     return buf[:n_real], n_gen[:n_real]
 
 
 def generate_reference(runner, params, prompt, *, gen_target: int,
                        max_new: int, prompt_pad: int, slots: int,
+                       arrival_slots: int, prefill_len: Optional[int] = None,
                        temperature: float = 0.0, base_key=None,
                        req_id: int = 0, cache_len=None):
-    """THE single-request reference path: one request, alone, in row 0 of
-    a ``slots``-row batch (the other rows are inert padding). Engine
-    outputs must match this bitwise per request."""
+    """THE single-request reference path: one request, alone, prefilled
+    in row 0 of an ``arrival_slots``-row buffer at ``prefill_len`` (the
+    length class of the engine tick that admitted it; default: the class
+    of its own length), written into row 0 of a ``slots``-row batch (the
+    other rows are inert padding) and decoded there. Engine outputs must
+    match this bitwise per request."""
     prompt = jnp.asarray(prompt, jnp.int32)
     pl = prompt.shape[0]
     padded = jnp.zeros((1, prompt_pad), jnp.int32).at[0, :pl].set(prompt)
@@ -169,7 +221,7 @@ def generate_reference(runner, params, prompt, *, gen_target: int,
         jnp.array([gen_target], jnp.int32), max_new=max_new,
         temperature=temperature, base_key=base_key,
         req_ids=jnp.array([req_id], jnp.int32), cache_len=cache_len,
-        pad_rows=slots)
+        pad_rows=slots, arrival_slots=arrival_slots, prefill_len=prefill_len)
     return toks[0, :int(n_gen[0])]
 
 
@@ -191,15 +243,13 @@ def decode_python_loop(runner, params, prompts, plens, gen_targets, *,
         cache_len = p + max_new
     caches = runner.init_caches(b, cache_len)
 
-    prefill = jax.jit(runner.prefill)
+    prefill = jax.jit(lambda params, caches, pre: _prefill(
+        runner, params, caches, pre, plens, req_ids, base_key, temperature))
     decode = jax.jit(runner.decode)
     sample = jax.jit(lambda lg, n: _row_sample(
         lg.astype(jnp.float32), base_key, req_ids, n, temperature))
 
-    logits_all, caches = prefill(params, caches, prompts)
-    last = jnp.take_along_axis(
-        logits_all, (plens - 1)[:, None, None], axis=1)[:, 0]
-    tok = sample(last, jnp.zeros((b,), jnp.int32))
+    tok, caches = prefill(params, caches, _prefill_buffer(prompts, plens))
     buf = [tok]
     pos = plens
     for i in range(1, max_new):

@@ -33,7 +33,9 @@ class ServeConfig:
     num_layers: Optional[int] = None  # override depth (benchmarks)
     num_slots: int = 8
     arrival_slots: int = 4
-    prompt_pad: int = 32          # admitted prompts pad to this length
+    # longest admitted prompt; a tick's arrivals pad to the smallest of
+    # four classes up to this length (prompt_pad / 8, / 4, / 2, itself)
+    prompt_pad: int = 32
     max_new: int = 32             # gen_buf depth / decode scan bound
     decode_chunk: int = 8
     temperature: float = 0.0

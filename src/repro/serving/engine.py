@@ -2,16 +2,23 @@
 
 Each call to the engine step (a) admits up to ``A`` newly arrived
 requests into free slots - in-trace, via a cumsum pack over the free-slot
-mask, (b) prefills the admitted rows (a batched padded prefill whose
-cache rows are WHERE-merged only for taken slots, ``lax.cond``-ed out
-entirely on ticks with no arrivals), and (c) decodes ``decode_chunk``
-tokens for every active slot in one ``lax.scan`` (slot-indexed KV
-writes, per-slot positions, active masking, on-device sampling).
+mask: arrival row ``i`` lands in the free slot of rank ``i``, (b)
+prefills the arrival buffer alone, cut to ``L`` positions, the smallest
+of four length classes (``runners.prefill_lengths``: ``P`` / 8, / 4, / 2,
+``P``) that holds the tick's longest admitted prompt, with the head on
+each row's last prompt position only (``runners.prefill_rows``); each
+taken row's cache positions are then written into its slot
+(``runners.write_rows``), ``[0, L)`` as computed and ``[L, P)`` as zeros.
+``lax.switch`` picks the class and ``lax.cond`` skips the whole sub-step
+on ticks with no arrivals. (c) decodes ``decode_chunk`` tokens for every
+active slot in one ``lax.scan`` (slot-indexed KV writes, per-slot
+positions, active masking, on-device sampling).
 
-All shapes are static - (N) slots, (A, P) arrival buffers, fixed chunk -
-so arrivals, completions, and re-plans never retrace: the step stays one
-compiled trace for the whole service lifetime (``step.trace_count``
-audits this, same idiom as ``core.splitting.make_plan_scorer``).
+All shapes are static - (N) slots, (A, P) arrival buffers, one prefill
+branch per length class, fixed chunk - so arrivals, completions, and
+re-plans never retrace: the step stays one compiled trace for the whole
+service lifetime (``step.trace_count`` audits this, same idiom as
+``core.splitting.make_plan_scorer``).
 
 Named scopes (``jax.named_scope``, op metadata only) label the device
 time: ``engine.admit``, ``engine.prefill``, ``engine.decode`` and
@@ -27,8 +34,10 @@ Invariant the bit-identity proof leans on: KV caches only ever hold
 FINITE values. Freed slots are not zeroed - their stale rows are masked
 out of attention by the per-row causal mask, and a masked FINITE value
 is a bitwise no-op on the softmax (exact-zero weight), whereas a NaN/Inf
-would poison the row max. Stale rows in the new request's decode region
-are overwritten the tick before they could first be attended.
+would poison the row max. An admitted slot's positions [P, cache_len)
+keep the previous occupant's values; like every stale row in the new
+request's decode region, each is overwritten the tick before it could
+first be attended.
 """
 from __future__ import annotations
 
@@ -38,15 +47,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.serving.batching import _row_sample
-from repro.serving.runners import cache_where
+from repro.serving.runners import (prefill_class, prefill_lengths,
+                                   prefill_rows, write_rows)
 
 Array = jax.Array
 
 
 class EngineState(NamedTuple):
     caches: object          # runner cache pytree, slot axis = num_slots
-    prompt: Array           # (N, P) int32 zero-padded admitted prompts
-    plen: Array             # (N,) int32 true prompt lengths
     gen_target: Array       # (N,) int32 tokens wanted per slot
     pos: Array              # (N,) int32 per-slot KV entry count
     last_tok: Array         # (N,) int32 token feeding the next decode
@@ -66,8 +74,6 @@ def init_engine_state(runner, num_slots: int, prompt_pad: int,
         cache_len = p + g
     state = EngineState(
         caches=runner.init_caches(n, cache_len),
-        prompt=jnp.zeros((n, p), jnp.int32),
-        plen=jnp.ones((n,), jnp.int32),
         gen_target=jnp.zeros((n,), jnp.int32),
         pos=jnp.zeros((n,), jnp.int32),
         last_tok=jnp.zeros((n,), jnp.int32),
@@ -149,37 +155,48 @@ def make_engine_step(runner, *, num_slots: int, arrival_slots: int,
             order = jnp.cumsum(free.astype(jnp.int32)) - 1  # rank among free
             take = free & (order < n_arr)                   # (N,)
             ai = jnp.clip(order, 0, a - 1)                  # arrival row/slot
+            rows = jnp.arange(a) < take.sum()               # taken rows
+            slot = jnp.zeros((a,), jnp.int32).at[               # row -> slot
+                jnp.where(take, order, a)].set(
+                    jnp.arange(n, dtype=jnp.int32), mode="drop")
             sel = lambda arr, old: jnp.where(take, arr[ai], old)
-            prompt = jnp.where(take[:, None], arr_prompt[ai], state.prompt)
-            plen = sel(arr_plen, state.plen)
             gen_target = sel(arr_gen, state.gen_target)
             req_id = sel(arr_req, state.req_id)
             n_gen = jnp.where(take, 0, state.n_gen)
             gen_buf = jnp.where(take[:, None], 0, state.gen_buf)
             active = state.active | take
 
-        # ---- prefill sub-step (only the taken rows land) ---------------
-        def do_prefill(operand):
-            caches, prompt, last_tok, pos_c = operand
-            logits_all, new_caches = runner.prefill(params, caches, prompt)
-            caches = cache_where(take, new_caches, caches)
-            last = jnp.take_along_axis(
-                logits_all, (plen - 1)[:, None, None], axis=1)[:, 0]
-            with jax.named_scope("engine.sample"):
-                tok0 = _row_sample(last.astype(jnp.float32), base_key,
-                                   req_id, jnp.zeros((n,), jnp.int32),
-                                   temperature)
-            last_tok = jnp.where(take, tok0, last_tok)
-            pos_c = jnp.where(take, plen, pos_c)
-            return caches, prompt, last_tok, pos_c
+        # ---- prefill sub-step: the arrival rows at a length class ------
+        # (each class returns its scratch padded to one shape, and the
+        # rows are written after the switch: a switch whose branches each
+        # updated the carried caches would copy them)
+        def at_length(length):
+            return lambda: prefill_rows(
+                runner, params, state.caches, arr_prompt[:, :length],
+                arr_plen, pad_to=prompt_pad)
 
-        operand = (state.caches, prompt, state.last_tok, state.pos)
+        def do_prefill(operand):
+            caches, last_tok, pos = operand
+            longest = jnp.max(jnp.where(rows, arr_plen, 1))
+            logits, fresh = jax.lax.switch(
+                prefill_class(prompt_pad, longest),
+                [at_length(l) for l in prefill_lengths(prompt_pad)])
+            caches = write_rows(caches, fresh, slot, rows)
+            with jax.named_scope("engine.sample"):
+                tok0 = _row_sample(logits.astype(jnp.float32), base_key,
+                                   arr_req, jnp.zeros((a,), jnp.int32),
+                                   temperature)
+            last_tok = jnp.where(take, tok0[ai], last_tok)
+            pos = jnp.where(take, arr_plen[ai], pos)
+            return caches, last_tok, pos
+
+        operand = (state.caches, state.last_tok, state.pos)
         with jax.named_scope("engine.prefill"):
             if skip_idle_prefill:
-                caches, _, last_tok, pos = jax.lax.cond(
+                caches, last_tok, pos = jax.lax.cond(
                     take.any(), do_prefill, lambda op: op, operand)
             else:
-                caches, _, last_tok, pos = do_prefill(operand)
+                caches, last_tok, pos = do_prefill(operand)
         gen_buf = jnp.where(take[:, None],
                             gen_buf.at[:, 0].set(last_tok), gen_buf)
         n_gen = jnp.where(take, 1, n_gen)
@@ -213,7 +230,7 @@ def make_engine_step(runner, *, num_slots: int, arrival_slots: int,
                 jax.lax.scan(dstep, carry, None, length=decode_chunk))
 
         state = EngineState(
-            caches=caches, prompt=prompt, plen=plen, gen_target=gen_target,
+            caches=caches, gen_target=gen_target,
             pos=pos, last_tok=last_tok, n_gen=n_gen, active=active,
             req_id=req_id, gen_buf=gen_buf, busy_steps=busy,
             decode_steps=state.decode_steps + decode_chunk,

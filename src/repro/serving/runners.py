@@ -3,14 +3,22 @@
 A runner exposes exactly two pure functions the engine composes into its
 jitted step:
 
-* ``prefill(params, caches, prompts)``: ``prompts`` (B, P) int32 ->
-  ``(logits (B, P, V), new_caches)`` - a fresh-sequence pass (scalar
-  cache index 0). The engine gathers each row's logits at its own
-  prompt length and WHERE-merges the cache rows of the slots it admitted.
+* ``prefill(params, caches, prompts, last=None)``: ``prompts`` (B, L)
+  int32 -> ``(logits, new_caches)`` - a fresh-sequence pass (scalar
+  cache index 0). Without ``last`` the logits are (B, L, V); with
+  ``last`` (B,) int32 positions only those rows reach the head and the
+  logits are (B, V). The engine prefills its arrival rows this way into
+  a scratch cache (:func:`prefill_rows`) and writes each taken row into
+  its slot (:func:`write_rows`).
 * ``decode(params, tok, caches, pos)``: ``tok`` (B, 1), ``pos`` (B,)
   per-slot entry counts -> ``(logits (B, V), new_caches)`` - one token
   per slot at each slot's OWN position (slot-indexed KV writes, see
   ``models.layers.kv_write``).
+
+Both runners' caches put the slot axis at -4 and the position axis at
+-3 of every leaf (``(R, B, C, KH, hd)`` stacks on one device,
+``(S, max_len, B, C, KH, hd)`` rings on a stage mesh); the scratch cache
+and the row writes rely on those two axes alone.
 
 Both backends restrict to attention-only architectures (MoE allowed
 under DROPLESS dispatch): padded batched prefill relies on causal
@@ -22,15 +30,14 @@ row's output is independent of its dispatch-group neighbours).
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
-
-Array = jax.Array
 
 
 def check_servable(cfg: ModelConfig) -> None:
@@ -67,11 +74,11 @@ class SingleDeviceRunner:
         return M.init_caches(self.cfg, num_slots, cache_len,
                              dtype=self.compute_dtype)
 
-    def prefill(self, params, caches, prompts):
+    def prefill(self, params, caches, prompts, last=None):
         logits, new_caches, _ = M.forward(
             params, prompts, self.cfg, caches=caches,
             cache_index=jnp.zeros((), jnp.int32), remat=False,
-            compute_dtype=self.compute_dtype)
+            compute_dtype=self.compute_dtype, last=last)
         return logits, new_caches
 
     def decode(self, params, tok, caches, pos):
@@ -121,25 +128,67 @@ class PipelineRunner:
         sharding = NamedSharding(self.mesh, PartitionSpec(self.stage_axis))
         return jax.tree.map(lambda c: jax.device_put(c, sharding), caches)
 
-    def prefill(self, params, caches, prompts):
-        return self._prefill(params, caches, prompts)
+    def prefill(self, params, caches, prompts, last=None):
+        return self._prefill(params, caches, prompts, last)
 
     def decode(self, params, tok, caches, pos):
         return self._decode(params, tok, caches, pos)
 
 
-def cache_where(mask: Array, new_caches, old_caches):
-    """Per-slot cache select: ``mask`` (B,) picks NEW rows, else old.
+def prefill_lengths(prompt_pad: int) -> tuple:
+    """The admission prefill's length classes: ``prompt_pad`` / 8, / 4,
+    / 2 and ``prompt_pad``, rounded up, duplicates dropped."""
+    return tuple(sorted({-(-prompt_pad // d) for d in (8, 4, 2, 1)}))
 
-    Works for both runner cache layouts - the slot axis is the unique
-    axis of size ``B = len(mask)``... which is ambiguous in general, so
-    the axis is located by matching ``B`` from the RIGHT (the slot axis
-    sits left of (kv_len, KH, hd) in both layouts: axis -4).
-    """
 
-    def one(n, o):
-        m = mask.reshape((-1,) + (1,) * 3)
-        return jnp.where(
-            jnp.expand_dims(m, tuple(range(n.ndim - 4))), n, o)
+def prefill_class(prompt_pad: int, longest):
+    """Index into ``prefill_lengths(prompt_pad)`` of the smallest class
+    that holds a prompt of ``longest`` tokens (a host int, or a traced
+    scalar for ``lax.switch``)."""
+    return sum(longest > n for n in prefill_lengths(prompt_pad)[:-1])
 
-    return jax.tree.map(one, new_caches, old_caches)
+
+def prefill_rows(runner, params, caches, prompts, plens, pad_to=None):
+    """Prefill ``prompts`` (A, L) as fresh sequences on a zero scratch
+    cache shaped like ``caches`` (read for its shapes and dtypes only)
+    but with A slots and L positions: at cache index 0 the pass writes all L positions before
+    it reads any. Returns ``(logits (A, V) at each row's position
+    plens - 1, scratch)``, the scratch zero-padded to ``pad_to``
+    positions if given (so that every length class returns one shape)."""
+    a, l = prompts.shape
+    scratch = jax.tree.map(
+        lambda c: jnp.zeros(c.shape[:-4] + (a, l) + c.shape[-2:], c.dtype),
+        caches)
+    logits, fresh = runner.prefill(params, scratch, prompts, last=plens - 1)
+    if pad_to is not None and pad_to > l:
+        fresh = jax.tree.map(
+            lambda f: jnp.pad(f, [(0, 0)] * (f.ndim - 3)
+                              + [(0, pad_to - l), (0, 0), (0, 0)]), fresh)
+    # row-major, as the layer loop carries the caches: left to the TPU
+    # compiler, the scratch leaves the engine's length-class switch with
+    # positions outside the heads, and the row writes then relayout the
+    # whole cache (twice for qwen2.5-3b's, in a described-v5e compile)
+    return logits, jax.tree.map(
+        lambda f: with_layout_constraint(
+            f, Layout(major_to_minor=tuple(range(f.ndim)))), fresh)
+
+
+def write_rows(caches, fresh, slots, taken):
+    """Write row ``i`` of the prefill scratch ``fresh`` (all its
+    positions) into slot ``slots[i]`` wherever ``taken[i]``: one
+    ``dynamic_update_slice`` a row; a row not taken writes back what its
+    slot held. A slot's positions past the scratch's keep their values:
+    finite, and masked until decode overwrites them."""
+
+    def write(c, f):
+        lead = (0,) * (c.ndim - 4)
+        size = f.shape[:-4] + (1,) + f.shape[-3:]
+        for i in range(f.shape[-4]):
+            at = lead + (slots[i], 0, 0, 0)
+            row = jax.lax.slice_in_dim(f, i, i + 1, axis=f.ndim - 4)
+            old = jax.lax.dynamic_slice(c, at, size)
+            c = jax.lax.dynamic_update_slice(
+                c, jnp.where(taken[i], row, old), at)
+        return c
+
+    return jax.tree.map(write, caches, fresh)

@@ -17,7 +17,11 @@ queued), ``serve.readback`` (the host waits for the report),
 ``serve.drain`` (completion bookkeeping), ``serve.sleep`` (idle wait) and
 ``serve.fault`` (outage handling), and one ``serve.request`` event per
 completion with its ``rid`` and its ``arrival``, ``admit``,
-``first_token`` and ``done`` times. Like the spans, these are on
+``first_token`` and ``done`` times. On a tick that admits, ``serve.admit``
+also holds two counter readings: ``serve.prefill_tokens``, the positions
+the engine's prefill computes (arrival rows x the tick's length class),
+and ``serve.prompt_tokens``, the admitted prompts' tokens; their ratio is
+the prefill's padding efficiency. Like the spans, these are on
 ``time.perf_counter``: ``arrival`` is the moment the service clock passed
 the request's arrival time (``run`` without ``realtime`` moves that clock
 ahead over idle stretches, so it may run ahead of ``perf_counter``).
@@ -32,6 +36,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.serving.config import ServeConfig
+from repro.serving.runners import prefill_class, prefill_lengths
 from repro.tracing import NULL_TRACER
 
 
@@ -391,6 +396,14 @@ class ServingService:
                     arrivals = (jnp.asarray(ap), jnp.asarray(al),
                                 jnp.asarray(ag), jnp.asarray(ar),
                                 jnp.int32(n_arr))
+                    if tr.enabled and n_arr:
+                        # the engine's prefill shape for these arrivals
+                        p = self.cfg.prompt_pad
+                        cls = prefill_class(p, int(al[:n_arr].max()))
+                        tr.count("serve.prefill_tokens",
+                                 len(al) * prefill_lengths(p)[cls])
+                        tr.count("serve.prompt_tokens",
+                                 int(al[:n_arr].sum()))
                 with tr.span("serve.dispatch"):
                     self.state, report = self._jstep(
                         self.params, self.state, *arrivals)

@@ -4,12 +4,15 @@ The contract under test (measured on the CPU backend, leaned on by the
 engine design):
 
 * per-ROW float results at a FIXED batch shape are bitwise invariant to
-  the other rows' contents and to which row a request occupies;
+  the other rows' contents and to which row a request occupies (but not
+  across shapes: a prefill cut to 4 positions is not bitwise the first
+  rows of one cut to 8);
 * therefore the single-request reference (``generate_reference``: the
-  request alone in a batch padded to the engine's slot count) must match
-  the engine's output for that request BITWISE, no matter when it
-  arrived, which slot it landed in, or what stale garbage the slot's KV
-  ring held;
+  request alone, prefilled in an arrival buffer at the length class of
+  the tick that admitted it, decoded in a batch padded to the engine's
+  slot count) must match the engine's output for that request BITWISE,
+  no matter when it arrived, which slot it landed in, or what stale
+  garbage the slot's KV ring held;
 * the engine step stays ONE compiled trace across arrivals, completions,
   idle ticks, and re-plans (all shapes static).
 """
@@ -27,7 +30,8 @@ from repro.serving import (
     decode_python_loop, poisson_trace,
 )
 from repro.serving.engine import init_engine_state, make_engine_step
-from repro.serving.runners import check_servable
+from repro.serving.runners import check_servable, prefill_lengths
+from repro.tracing import Tracer
 
 
 def _model(num_layers=2):
@@ -113,20 +117,57 @@ def test_fused_generate_matches_python_loop():
 # engine vs single-request reference, bitwise
 
 
+# prompt lengths 1-16 at prompt_pad 16 (classes 2/4/8/16), in groups that
+# arrive together, far enough apart that each group drains first: a group
+# of several is admitted 2 a tick at the class of the pair's longest
+_GROUPS = ((1,), (3, 2), (5, 8, 1), (16, 9), (12, 4, 15, 6, 7, 11, 10,
+                                             13, 14))
+
+
+def _grouped_trace(vocab, seed=3):
+    rng = np.random.default_rng(seed)
+    out = []
+    for g, lens in enumerate(_GROUPS):
+        for pl in lens:
+            out.append(Request(
+                rid=len(out),
+                prompt=rng.integers(0, vocab, pl).astype(np.int32),
+                gen_target=int(rng.integers(2, 9)),
+                arrival_time=1000.0 * (g + 1)))
+    return out
+
+
+def _prefill_lens(events, arrival_slots):
+    """rid -> the prefill length of the tick that admitted it, from the
+    tracer: the serve.prefill_tokens reading sits in that tick's
+    serve.admit span, and the tick records the rids it packed."""
+    parent = {e["id"]: e.get("parent") for e in events}
+    packed = {e["id"]: e["packed"] for e in events
+              if e["name"] == "serve.tick" and "packed" in e}
+    return {rid: e["value"] // arrival_slots for e in events
+            if e["name"] == "serve.prefill_tokens"
+            for rid in packed[parent[e["parent"]]]}
+
+
 def _check_engine_vs_reference(temperature):
-    cfg = ServeConfig(num_slots=3, arrival_slots=2, prompt_pad=8, max_new=8,
-                      decode_chunk=2, temperature=temperature)
-    svc = ServingService(cfg)
-    # 7 requests through 3 slots: arrivals land mid-flight of earlier
-    # requests and every slot is reused at least once
-    trace = _trace(svc.model_cfg, n=7)
+    cfg = ServeConfig(num_slots=3, arrival_slots=2, prompt_pad=16,
+                      max_new=8, decode_chunk=2, temperature=temperature)
+    tr = Tracer()
+    svc = ServingService(cfg, tracer=tr)
+    # requests through 3 slots: arrivals land mid-flight of earlier
+    # requests, every slot is reused, and ticks admit at every class
+    trace = _grouped_trace(svc.model_cfg.vocab_size)
     res = svc.run(trace)
+    tr.close()
     assert res["num_requests"] == len(trace)
+    lens = _prefill_lens(tr.events(), cfg.arrival_slots)
+    assert set(lens.values()) == set(prefill_lengths(cfg.prompt_pad))
     for r in trace:
         ref = generate_reference(
             svc.runner, svc.params, r.prompt, gen_target=r.gen_target,
             max_new=cfg.max_new, prompt_pad=cfg.prompt_pad,
-            slots=cfg.num_slots, temperature=temperature,
+            slots=cfg.num_slots, arrival_slots=cfg.arrival_slots,
+            prefill_len=lens[r.rid], temperature=temperature,
             base_key=svc.base_key, req_id=r.rid)
         got = res["completions"][r.rid]
         assert np.array_equal(got, np.asarray(ref)), (
@@ -185,9 +226,80 @@ def test_slot_reuse_survives_poisoned_stale_cache():
     pr_b = rng.integers(0, cfg.vocab_size, 4).astype(np.int32)
     state, got = admit_and_drain(state, 1, pr_b, 5)
     ref = generate_reference(runner, params, pr_b, gen_target=5, max_new=g,
-                             prompt_pad=p, slots=n, base_key=key, req_id=1)
+                             prompt_pad=p, slots=n, arrival_slots=1,
+                             base_key=key, req_id=1)
     assert np.array_equal(got, np.asarray(ref))
     assert len(step.trace_count) == 1
+
+
+def test_admitting_tick_leaves_untaken_slots_unchanged():
+    """The prefill writes only the slots it takes, and of those only the
+    first ``prompt_pad`` positions (the tick's L computed, zeros past
+    them): every other slot's cache rows, ``last_tok`` and ``pos`` come
+    out bitwise as they went in, and so do a taken slot's positions past
+    ``prompt_pad``. ``decode_chunk`` 0 isolates the admission tick; one
+    arrival in two arrival rows makes the untaken row write back."""
+    cfg, params = _model()
+    runner = SingleDeviceRunner(cfg)
+    n, a, p, g = 4, 2, 8, 6
+    step = jax.jit(make_engine_step(runner, num_slots=n, arrival_slots=a,
+                                    prompt_pad=p, max_new=g,
+                                    decode_chunk=0))
+    rng = np.random.default_rng(7)
+
+    def arrivals(lens, rid0):
+        ap = np.zeros((a, p), np.int32)
+        for i, pl in enumerate(lens):
+            ap[i, :pl] = rng.integers(0, cfg.vocab_size, pl)
+        al = np.ones((a,), np.int32)
+        al[:len(lens)] = lens
+        ar = np.full((a,), -1, np.int32)
+        ar[:len(lens)] = rid0 + np.arange(len(lens))
+        return (jnp.asarray(ap), jnp.asarray(al),
+                jnp.full((a,), g, jnp.int32), jnp.asarray(ar),
+                jnp.int32(len(lens)))
+
+    state = init_engine_state(runner, n, p, g)
+    state, _ = step(params, state, *arrivals([5, 3], 0))  # slots 0 and 1
+    # distinct finite garbage everywhere, so any stray write shows
+    state = state._replace(caches=jax.tree.map(
+        lambda c: jax.random.normal(jax.random.PRNGKey(1), c.shape, c.dtype),
+        state.caches))
+    before = jax.tree.map(np.asarray, state)
+    state, rep = step(params, state, *arrivals([3], 2))   # class 4, slot 2
+    after = jax.tree.map(np.asarray, state)
+
+    assert np.asarray(rep["admitted"]).tolist() == [False, False, True, False]
+    untaken = [0, 1, 3]
+    for f in ("last_tok", "pos"):
+        assert np.array_equal(getattr(after, f)[untaken],
+                              getattr(before, f)[untaken]), f
+    assert after.pos[2] == 3
+    for old, new in zip(jax.tree.leaves(before.caches),
+                        jax.tree.leaves(after.caches)):
+        assert np.array_equal(new[:, untaken], old[:, untaken])
+        assert np.array_equal(new[:, 2, p:], old[:, 2, p:])
+        assert not (new[:, 2, 4:p] != 0).any()
+        assert (new[:, 2, :3] != old[:, 2, :3]).all()
+
+
+def test_forward_last_rows_match_full_forward():
+    """``M.forward(..., last=)`` returns the full forward's logits at
+    those positions, (B, V), and the same caches."""
+    cfg, params = _model()
+    b, p = 3, 8
+    rng = np.random.default_rng(2)
+    prompts = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, p)), jnp.int32)
+    last = jnp.asarray([7, 0, 4], jnp.int32)
+    caches = M.init_caches(cfg, b, p + 4, dtype=jnp.float32)
+    kw = dict(caches=caches, cache_index=jnp.zeros((), jnp.int32),
+              compute_dtype=jnp.float32)
+    full, c_full, _ = M.forward(params, prompts, cfg, **kw)
+    rows, c_rows, _ = M.forward(params, prompts, cfg, last=last, **kw)
+    assert rows.shape == (b, cfg.vocab_size)
+    assert jnp.array_equal(rows, full[jnp.arange(b), last])
+    for x, y in zip(jax.tree.leaves(c_full), jax.tree.leaves(c_rows)):
+        assert jnp.array_equal(x, y)
 
 
 # ---------------------------------------------------------------------------
@@ -362,6 +474,40 @@ print('MOE_SERVE_OK', len(cases))
 """,
         n_devices=2)
     assert "MOE_SERVE_OK 2" in out
+
+
+def test_pipeline_prefill_last_rows(subproc):
+    """The split prefill with ``last`` positions sends only those rows
+    through the head on the last stage: the full pass's logits at those
+    positions, (B, V), and the same stage caches."""
+    out = subproc(
+        """
+import jax, jax.numpy as jnp, numpy as np
+from dataclasses import replace
+from repro.configs import get_config
+from repro.core.pipeline import (make_stage_mesh, pipeline_serve_fns,
+                                 stage_kv_caches)
+from repro.models.model import init_params
+
+cfg = replace(get_config('qwen2.5-3b').reduced(), num_layers=2)
+bounds = (1, 2)
+params = init_params(jax.random.PRNGKey(0), cfg)
+prefill, _ = pipeline_serve_fns(cfg, make_stage_mesh(2), bounds)
+b, p = 3, 8
+prompts = jnp.asarray(np.random.default_rng(0).integers(
+    0, cfg.vocab_size, (b, p)), jnp.int32)
+last = jnp.asarray([7, 0, 4], jnp.int32)
+caches = stage_kv_caches(cfg, bounds, b, p + 4)
+full, c_full = jax.jit(prefill)(params, caches, prompts)
+rows, c_rows = jax.jit(prefill)(params, caches, prompts, last)
+assert rows.shape == (b, cfg.vocab_size)
+assert jnp.array_equal(rows, full[jnp.arange(b), last])
+for x, y in zip(jax.tree.leaves(c_full), jax.tree.leaves(c_rows)):
+    assert jnp.array_equal(x, y)
+print('PIPE_LAST_OK')
+""",
+        n_devices=2)
+    assert "PIPE_LAST_OK" in out
 
 
 def test_decode_scan_does_not_copy_the_cache():

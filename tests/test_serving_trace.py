@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from repro.serving import ServeConfig, ServingService, poisson_trace
+from repro.serving.runners import prefill_lengths
 from repro.tracing import Tracer
 
 CFG = ServeConfig(num_layers=2, num_slots=2, arrival_slots=1, prompt_pad=8,
@@ -103,3 +104,27 @@ def test_untraced_service_keeps_null_tracer(runs):
     plain = runs[0]
     assert not plain.tracer.enabled
     assert plain.tracer.events() == []
+
+
+def test_admitting_ticks_count_prefill_and_prompt_tokens(runs):
+    """Each tick that admits records, in its packing serve.admit span,
+    the positions the prefill computes (arrival rows x the smallest class
+    holding the longest packed prompt) and the packed prompts' tokens."""
+    _, _, _, _, events = runs
+    plen = {r.rid: r.plen for r in _trace()}
+    parent = {e["id"]: e.get("parent") for e in events}
+    ticks = {e["id"]: e for e in events
+             if e["name"] == "serve.tick" and e.get("packed")}
+    counts = {}
+    for e in events:
+        if e["name"] in ("serve.prefill_tokens", "serve.prompt_tokens"):
+            assert [x["name"] for x in events
+                    if x["id"] == e["parent"]] == ["serve.admit"]
+            counts.setdefault(parent[e["parent"]], {})[e["name"]] = e["value"]
+    assert set(counts) == set(ticks)
+    for tid, c in counts.items():
+        lens = [plen[r] for r in ticks[tid]["packed"]]
+        cls = min(n for n in prefill_lengths(CFG.prompt_pad)
+                  if n >= max(lens))
+        assert c == {"serve.prefill_tokens": CFG.arrival_slots * cls,
+                     "serve.prompt_tokens": sum(lens)}
