@@ -121,7 +121,7 @@ class BenchConfig:
         """Population mesh for the configured device count (None = no mesh)."""
         if self.shard_devices is None:
             return None
-        from repro.launch.mesh import make_population_mesh
+        from repro.distribution.mesh import make_population_mesh
 
         return make_population_mesh(self.shard_devices)
 

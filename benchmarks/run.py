@@ -28,11 +28,9 @@ ALL = [
     "fig9_example",
     "fig10_leakage_attack",
     "table_power",
-    "roofline",
     "throughput",
     "pipeline",
     "serving",
-    "moe_dispatch",
     "zoo_plan_scoring",
 ]
 

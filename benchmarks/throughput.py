@@ -364,7 +364,7 @@ from repro.core.scenario import (
     make_population_rollout, scenario_grid, stack_scenarios,
 )
 from repro.distribution import population as PD
-from repro.launch.mesh import make_population_mesh
+from repro.distribution.mesh import make_population_mesh
 
 N, NUM_ENVS, CHUNKS = {n}, {num_envs}, {chunks}
 env = MHSLEnv(profile=resnet101_profile(batch=1))

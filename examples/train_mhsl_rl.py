@@ -54,7 +54,7 @@ def main():
     sac_cfg = SACConfig()
     mesh = None
     if args.shard_envs:
-        from repro.launch.mesh import make_population_mesh
+        from repro.distribution.mesh import make_population_mesh
 
         mesh = make_population_mesh()
         print(f"      population mesh: {len(jax.devices())} devices, "

@@ -9,7 +9,7 @@ from __future__ import annotations
 import dataclasses
 import importlib
 from dataclasses import dataclass, field, replace
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 ArchType = str  # 'dense' | 'moe' | 'ssm' | 'hybrid' | 'vlm' | 'audio'
 
@@ -19,7 +19,6 @@ class MoEConfig:
     num_experts: int = 0
     top_k: int = 0
     expert_d_ff: int = 0          # per-expert FFN hidden size
-    capacity_factor: float = 1.25
     router_aux_weight: float = 1e-2
     # expert parallelism: this layer holds experts
     # [expert_start, expert_start + num_held) of the num_experts the router
@@ -27,13 +26,15 @@ class MoEConfig:
     # output; the other shares' parts are computed where they are held
     num_held: int = 0
     expert_start: int = 0
-    # every `moe_every`-th block is MoE (1 = every block); used by hybrids
+    # every `moe_every`-th block is MoE (1 = every block); used by hybrids.
+    # Tokens are routed dropless (layers.moe_dropless): sort-based grouping,
+    # every routed token computed.
     moe_every: int = 1
-    # token routing: "dropless" (sort-based grouping, every routed token
-    # computed - layers.moe_apply_dropless) or "capacity" (the classic
-    # ceil(T*k*cf/E) buffer with token dropping - layers.moe_apply).
-    # capacity_factor only matters under "capacity".
-    dispatch: str = "dropless"
+
+    @property
+    def dispatch(self) -> str:
+        # read by bench/drive_train_split.py's configuration check only
+        return "dropless"
 
     @property
     def enabled(self) -> bool:

@@ -187,9 +187,10 @@ def train_sac(
     of ``num_envs`` the final chunk still trains on the full population
     but only the first ``episodes`` entries are reported.
 
-    ``mesh`` (``launch.mesh.make_population_mesh``) shards the ``num_envs``
-    axis of env states / key batches and the replay buffer's capacity axis
-    across devices; agent params and optimizer state are replicated. The
+    ``mesh`` (``distribution.mesh.make_population_mesh``) shards the
+    ``num_envs`` axis of env states / key batches and the replay buffer's
+    capacity axis across devices; agent params and optimizer state are
+    replicated. The
     compiled chunk functions are unchanged - jit propagates the committed
     input shardings - so a 1-device mesh is bit-identical to ``mesh=None``.
 

@@ -46,7 +46,7 @@ dtype), so the wire pays bf16 bytes even when stages accumulate in fp32 -
 the paper's Eq. 1/4 transmissions priced per
 ``repro.core.transport``'s link model.
 
-A 2-D (stage x env) mesh (``launch.mesh.make_stage_env_mesh``) composes
+A 2-D (stage x env) mesh (``distribution.mesh.make_stage_env_mesh``) composes
 this pipeline with data parallelism: pass ``env_axis`` and the
 microbatch-row dim of ``tokens``/``labels`` shards over ``env`` while
 stage params replicate across it; loss and grads are ``pmean``-ed over
@@ -82,7 +82,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.launch.mesh import make_stage_mesh  # noqa: F401  (re-export)
+from repro.distribution.mesh import make_stage_mesh  # noqa: F401  (re-export)
 from repro.models import model as M
 from repro.models import layers as L
 
@@ -872,11 +872,6 @@ def pipeline_serve_fns(cfg: ModelConfig, mesh: Mesh, boundaries: Sequence[int],
             "pipeline serving: SSM/hybrid archs are unservable - padded "
             "batched prefill relies on causal masking, which protects KV "
             "attention but not recurrent scan state")
-    if any(is_moe for _, is_moe, _ in sig) and cfg.moe.dispatch != "dropless":
-        raise ValueError(
-            "pipeline serving: capacity-dropping MoE is unservable (padded "
-            "prefill rows steal expert capacity from real rows); set "
-            "moe.dispatch='dropless'")
     uniq_keys = [_sig_field_keys(cfg, u) for u in uniq_sigs]
     s_stages = len(boundaries)
     lens = stage_lengths(boundaries)

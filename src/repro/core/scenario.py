@@ -374,7 +374,7 @@ def train_population(env, cfg, scenarios: ScenarioParams, *,
     (every scenario shares the chunk schedule, reset keys, and action
     keys, so sweep points differ only by their physics).
 
-    ``mesh`` (``launch.mesh.make_population_mesh``) shards the SCENARIO
+    ``mesh`` (``distribution.mesh.make_population_mesh``) shards the SCENARIO
     axis across devices: per-scenario agent params, optimizer state,
     replay buffers, and the stacked ``ScenarioParams`` all carry their
     leading ``N`` axis on the mesh, while the shared reset/action keys are

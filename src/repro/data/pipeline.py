@@ -1,4 +1,4 @@
-"""Synthetic data pipeline + ShapeDtypeStruct input specs for dry-runs.
+"""Synthetic data pipeline + ShapeDtypeStruct input specs.
 
 The data pipeline is deterministic and seeded (no dataset downloads on this
 box); it produces next-token LM batches plus stub modality features for
@@ -50,7 +50,7 @@ def synthetic_stream(
 
 
 # ---------------------------------------------------------------------------
-# dry-run input specs (no allocation)
+# input specs (no allocation)
 # ---------------------------------------------------------------------------
 
 

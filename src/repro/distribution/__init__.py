@@ -1,6 +1,5 @@
 from repro.distribution.sharding import (
     batch_sharding,
-    cache_shardings,
     named,
     param_shardings,
     population_axes,
@@ -11,7 +10,6 @@ from repro.distribution.sharding import (
 
 __all__ = [
     "batch_sharding",
-    "cache_shardings",
     "named",
     "param_shardings",
     "population_axes",

@@ -1,12 +1,8 @@
-"""Production mesh definitions.
+"""Mesh builders over the devices a host has.
 
-``make_production_mesh`` is a FUNCTION (not a module constant) so importing
-this module never touches jax device state. The dry-run launcher forces 512
-host devices via XLA_FLAGS *before* any jax import.
-
-Target hardware: TPU v5e pods, 256 chips/pod (16x16 ICI torus); multi-pod =
-2 pods over DCN. Axes: 'data' (FSDP+DP), 'model' (tensor parallel), 'pod'
-(pure DP over DCN).
+The split executor's stage meshes, the RL engine's population mesh and a
+small data x model mesh for the GSPMD train step. Each is a FUNCTION, so
+importing this module never touches jax device state.
 """
 from __future__ import annotations
 
@@ -17,12 +13,6 @@ def _make_mesh(shape, axes):
     """``jax.make_mesh`` with every axis Auto (GSPMD-partitioned)."""
     types = (jax.sharding.AxisType.Auto,) * len(axes)
     return jax.make_mesh(shape, axes, axis_types=types)
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -39,8 +29,7 @@ def make_stage_mesh(n_stages: int, stage_axis: str = "stage"):
     this axis play the paper's wireless activation/gradient hops. Builds
     ``Mesh`` directly from an explicit device slice (``jax.make_mesh``
     picks devices itself, and the stage order must be pinned), so it does
-    NOT go through ``_make_mesh`` - it lives here with the other mesh
-    constructors for discoverability.
+    NOT go through ``_make_mesh``.
     """
     import numpy as np
     from jax.sharding import Mesh

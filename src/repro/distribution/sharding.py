@@ -1,11 +1,10 @@
-"""Sharding rules: logical roles -> PartitionSpecs on the production mesh.
+"""Sharding rules: logical roles -> PartitionSpecs on a data x model mesh.
 
 Strategy (MaxText-style FSDP + tensor parallelism):
   * weights: one dim sharded over 'data' (FSDP / ZeRO-3) and one over
     'model' (tensor parallel), chosen per logical role, only when divisible;
-  * activations/batch: leading batch dim over ('pod', 'data');
-  * KV caches: heads over 'model' when divisible, else cache length over
-    'model' (GQA with few KV heads cannot head-shard across 16-way TP).
+  * batch: leading batch dim over ('pod', 'data'); GSPMD propagates the
+    activations' shardings from the parameters and the batch.
 
 The 'pod' axis (multi-pod mesh) carries pure data parallelism: weights are
 replicated across pods (DCN is too slow for cross-pod FSDP) and gradients
@@ -13,8 +12,7 @@ all-reduce over ('pod', 'data').
 """
 from __future__ import annotations
 
-import re
-from typing import Any, Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -74,9 +72,9 @@ ENV_AXIS = "env"
 def population_axes(mesh: Mesh, num: int):
     """Mesh axes for a population axis of size ``num``.
 
-    A dedicated ``'env'`` axis (``launch.mesh.make_population_mesh``) wins;
-    otherwise the population rides the pure-data-parallel prefix of a
-    production mesh (``('pod', 'data')``), largest divisible prefix. Returns
+    A dedicated ``'env'`` axis (``distribution.mesh.make_population_mesh``)
+    wins; otherwise the population rides the pure-data-parallel prefix of a
+    data x model mesh (``('pod', 'data')``), largest divisible prefix. Returns
     ``None`` (replicate) when nothing divides ``num``.
     """
     if ENV_AXIS in mesh.axis_names:
@@ -184,47 +182,12 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
-def param_shardings(params_shape, cfg: ModelConfig, mesh: Mesh, *, mode: str = "train"):
-    """Tree of NamedShardings matching a params (or opt-state) shape tree.
-
-    mode='train': FSDP over 'data' + tensor parallel over 'model'.
-    mode='serve': weights RESIDENT - the 'data' axis is dropped from weight
-    specs (no per-layer FSDP all-gather at decode; weights cost 16x more
-    HBM per chip but decode stops being gather-bound).
-    """
+def param_shardings(params_shape, cfg: ModelConfig, mesh: Mesh):
+    """Tree of NamedShardings matching a params (or opt-state) shape tree:
+    FSDP over 'data' + tensor parallel over 'model'."""
 
     def one(path, leaf):
-        sp = spec_for_param(_path_str(path), tuple(leaf.shape), cfg, mesh)
-        if mode == "serve":
-            sp = P(*[None if ax == "data" else ax for ax in (tuple(sp) + (None,) * 0)])
-        return NamedSharding(mesh, sp)
+        return NamedSharding(
+            mesh, spec_for_param(_path_str(path), tuple(leaf.shape), cfg, mesh))
 
     return jax.tree_util.tree_map_with_path(one, params_shape)
-
-
-# ---------------------------------------------------------------------------
-# cache sharding
-# ---------------------------------------------------------------------------
-
-
-def cache_shardings(caches_shape, cfg: ModelConfig, mesh: Mesh, batch: int):
-    """KV caches: (repeats, B, len, KH, hd) / SSM: (repeats, B, H, P, N)."""
-    baxes = batch_axes(mesh, batch)
-
-    def one(path, leaf):
-        p = _path_str(path)
-        dims = leaf.shape
-        leafname = p.split("/")[-1]
-        if leafname in ("k", "v"):  # (repeats, B, L, KH, hd)
-            kh_ax = _maybe("model", dims[3], mesh)
-            len_ax = _maybe("model", dims[2], mesh) if kh_ax is None else None
-            return named(mesh, None, baxes, len_ax, kh_ax, None)
-        if leafname == "ssm":  # (repeats, B, H, P, N)
-            h_ax = _maybe("model", dims[2], mesh)
-            return named(mesh, None, baxes, h_ax, None, None)
-        if leafname == "conv":  # (repeats, B, K-1, C)
-            c_ax = _maybe("model", dims[3], mesh)
-            return named(mesh, None, baxes, None, c_ax)
-        return named(mesh, *([None] * len(dims)))
-
-    return jax.tree_util.tree_map_with_path(one, caches_shape)

@@ -1,8 +1,7 @@
-"""Post-SPMD HLO analysis: collective bytes + roofline terms.
+"""Post-SPMD HLO analysis: collective counts and bytes.
 
 The compiled module text is PER-DEVICE (SPMD partitioned), so shapes parsed
-here are per-device shard shapes and ``cost_analysis()`` numbers are
-per-device too. Roofline terms are therefore per-chip directly.
+here are per-device shard shapes.
 
 Wire-byte factors per collective (ring algorithms, n = replica group size):
   all-reduce        2 (n-1)/n * result_bytes
@@ -15,7 +14,7 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
-from typing import Dict, Optional
+from typing import Dict
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -165,14 +164,6 @@ class CollectiveStats:
     wire_bytes: Dict[str, float] = field(default_factory=dict)
     counts: Dict[str, int] = field(default_factory=dict)
 
-    @property
-    def total_wire_bytes(self) -> float:
-        return sum(self.wire_bytes.values())
-
-    @property
-    def total_result_bytes(self) -> int:
-        return sum(self.result_bytes.values())
-
 
 def parse_collectives(hlo_text: str, *, loop_aware: bool = False) -> CollectiveStats:
     """Sum collective bytes in the module.
@@ -253,60 +244,3 @@ def pipeline_collective_counts(
     """
     stats = parse_collectives(hlo_text, loop_aware=loop_aware)
     return {k: c / n_ticks for k, c in stats.counts.items()}
-
-
-# ---------------------------------------------------------------------------
-# roofline
-# ---------------------------------------------------------------------------
-
-# TPU v5e per-chip constants (assignment-specified)
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link (wire-byte estimate treated as per-chip stream)
-
-
-@dataclass
-class Roofline:
-    flops_per_device: float
-    hbm_bytes_per_device: float
-    wire_bytes_per_device: float
-    compute_s: float
-    memory_s: float
-    collective_s: float
-    dominant: str
-    model_flops: float = 0.0  # 6 N D (train) / 2 N D (decode), per device
-    useful_ratio: float = 0.0
-
-    def as_dict(self):
-        return dict(
-            flops_per_device=self.flops_per_device,
-            hbm_bytes_per_device=self.hbm_bytes_per_device,
-            wire_bytes_per_device=self.wire_bytes_per_device,
-            compute_s=self.compute_s,
-            memory_s=self.memory_s,
-            collective_s=self.collective_s,
-            dominant=self.dominant,
-            model_flops=self.model_flops,
-            useful_ratio=self.useful_ratio,
-        )
-
-
-def roofline_from(cost: Optional[dict], coll: CollectiveStats, model_flops_per_device: float = 0.0) -> Roofline:
-    flops = float(cost.get("flops", 0.0)) if cost else 0.0
-    hbm = float(cost.get("bytes accessed", 0.0)) if cost else 0.0
-    wire = coll.total_wire_bytes
-    c_s = flops / PEAK_FLOPS_BF16
-    m_s = hbm / HBM_BW
-    k_s = wire / ICI_BW
-    dom = max((("compute", c_s), ("memory", m_s), ("collective", k_s)), key=lambda t: t[1])[0]
-    return Roofline(
-        flops_per_device=flops,
-        hbm_bytes_per_device=hbm,
-        wire_bytes_per_device=wire,
-        compute_s=c_s,
-        memory_s=m_s,
-        collective_s=k_s,
-        dominant=dom,
-        model_flops=model_flops_per_device,
-        useful_ratio=(model_flops_per_device / flops) if flops else 0.0,
-    )

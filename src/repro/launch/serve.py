@@ -58,7 +58,7 @@ def run_static(cfg, trace, *, warmup: bool = False):
         runner = SingleDeviceRunner(model_cfg, compute_dtype=dtype)
     else:
         from repro.core.pipeline import PipelineConfig
-        from repro.launch.mesh import make_stage_mesh
+        from repro.distribution.mesh import make_stage_mesh
 
         runner = PipelineRunner(
             model_cfg, make_stage_mesh(len(cfg.boundaries)), cfg.boundaries,

@@ -1,11 +1,11 @@
-"""Training launcher: real execution at reduced scale, or full-scale lower.
+"""Training launcher: the GSPMD train step on a host mesh.
 
     PYTHONPATH=src python -m repro.launch.train --arch stablelm-1.6b \
         --steps 50 [--reduced] [--data-par 2 --model-par 2]
 
-On this CPU container use --reduced (default). On a real TPU slice, drop
---reduced and the same code path shards the full architecture over the
-production mesh.
+--reduced is the default. The step runs under jit with its parameter,
+optimizer-state and batch shardings on a data x model host mesh; GSPMD
+propagates the activations' shardings from those.
 """
 import argparse
 import os
@@ -22,9 +22,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import save_pytree
 from repro.configs import get_config
 from repro.data import synthetic_stream
-from repro.distribution.context import activation_sharding
+from repro.distribution.mesh import make_host_mesh
 from repro.distribution.sharding import batch_axes, param_shardings
-from repro.launch.mesh import make_host_mesh
 from repro.models import init_params, make_train_step
 from repro.optim import adamw, linear_warmup_cosine
 
@@ -66,13 +65,12 @@ def main(argv=None):
     jitted = jax.jit(step_fn, in_shardings=(psh, osh, bsh), out_shardings=(psh, osh, None))
 
     t0 = time.time()
-    with activation_sharding(mesh, baxes):
-        for step in range(args.steps):
-            batch = jax.tree.map(lambda a, s: jax.device_put(a, s), next(stream), bsh)
-            params, opt_state, m = jitted(params, opt_state, batch)
-            if step % 10 == 0 or step == args.steps - 1:
-                print(f"step {step:4d}  loss {float(m['loss']):.4f}  "
-                      f"({time.time() - t0:.1f}s)", flush=True)
+    for step in range(args.steps):
+        batch = jax.tree.map(jax.device_put, next(stream), bsh)
+        params, opt_state, m = jitted(params, opt_state, batch)
+        if step % 10 == 0 or step == args.steps - 1:
+            print(f"step {step:4d}  loss {float(m['loss']):.4f}  "
+                  f"({time.time() - t0:.1f}s)", flush=True)
     if args.ckpt:
         save_pytree(params, args.ckpt)
         print(f"saved -> {args.ckpt}")

@@ -7,14 +7,12 @@ dtype (bf16 by default) with f32 softmax/norm accumulation.
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distribution.context import constrain
 
 Array = jax.Array
 
@@ -170,9 +168,6 @@ def chunked_attention(
     qs = q.reshape(b, n_q, q_chunk, h, hd).transpose(1, 0, 2, 3, 4)
     ks = k.reshape(b, n_kv, kv_chunk, kh, hd).transpose(1, 0, 2, 3, 4)
     vs = v.reshape(b, n_kv, kv_chunk, kh, hd).transpose(1, 0, 2, 3, 4)
-    qs = constrain(qs, {1: "batch", 3: "model"})
-    ks = constrain(ks, {1: "batch"})
-    vs = constrain(vs, {1: "batch"})
     scale = 1.0 / math.sqrt(hd)
 
     def q_body(_, qc_i):
@@ -189,7 +184,6 @@ def chunked_attention(
                 jnp.einsum("bqhd,bkhd->bhqk", qc, kc_r, preferred_element_type=jnp.float32)
                 * scale
             )
-            s = constrain(s, {0: "batch", 1: "model"})
             bias = _mask_bias(qpos, kpos, window)
             # mask out kv padding
             bias = jnp.where((kpos < skv)[None, :], bias, -jnp.inf)
@@ -203,7 +197,6 @@ def chunked_attention(
             acc = acc * corr[..., None] + jnp.einsum(
                 "bhqk,bkhd->bhqd", p.astype(vc_r.dtype), vc_r
             ).astype(jnp.float32)
-            acc = constrain(acc, {0: "batch", 1: "model"})
             return (acc, m_new, l_new), None
 
         acc0 = jnp.zeros((b, h, q_chunk, hd), jnp.float32)
@@ -282,20 +275,9 @@ def attention_apply(
         q = q + params["bq"].astype(x.dtype)
         k = k + params["bk"].astype(x.dtype)
         v = v + params["bv"].astype(x.dtype)
-    from repro.distribution.context import kv_seq_shard_enabled, model_axis_divides
-
-    q = constrain(q.reshape(b, s, h, hd), {0: "batch", 2: "model"})
-    # Fresh K/V sharding in cache paths is a measured knob (SPerf log):
-    # head-sharding when the TP axis divides kh; otherwise SEQUENCE-sharding
-    # aligns fresh KV with the length-sharded cache and removes a per-layer
-    # replicate-reshard ("involuntary full rematerialization", ~4 GB/layer
-    # all-gather on pixtral prefill_32k) - but it REGRESSES collectives on
-    # kh=4 GQA and hd=192 archs, so it is opt-in per architecture.
-    kv_dim = 2
-    if kv_cache is not None and not model_axis_divides(kh) and kv_seq_shard_enabled():
-        kv_dim = 1
-    k = constrain(k.reshape(b, s, kh, hd), {0: "batch", kv_dim: "model"})
-    v = constrain(v.reshape(b, s, kh, hd), {0: "batch", kv_dim: "model"})
+    q = q.reshape(b, s, h, hd)
+    k = k.reshape(b, s, kh, hd)
+    v = v.reshape(b, s, kh, hd)
     if cfg.qk_norm:
         # every path (training, prefill, cached decode) normalises the
         # fresh q and k here, so the cache holds normalised, rotated keys
@@ -340,41 +322,27 @@ def attention_apply(
             w = jax.nn.softmax(scores, axis=-1)
             out = jnp.einsum("bhqk,bkhd->bqhd", w.astype(v.dtype), _repeat_kv(cv, h // kh))
         else:
-            from repro.distribution.context import active as ctx_active
-
-            if (
-                s == 1
-                and ctx_active()
-                and not model_axis_divides(kh)
-                and model_axis_divides(cache_len)
-            ):
-                # distributed flash-decoding over the length-sharded cache
-                from repro.models.flash_decode import flash_decode
-
-                out = flash_decode(q, ck, cv, cache_index,
-                                   window=cfg.attention_window)
+            kpos = jnp.arange(cache_len)
+            qpos = positions  # (s,) absolute, or (B, s) per-row
+            ok = kpos <= qpos[..., None]
+            if vec_idx:
+                ok &= kpos < (cache_index[:, None, None] + s)
             else:
-                kpos = jnp.arange(cache_len)
-                qpos = positions  # (s,) absolute, or (B, s) per-row
-                ok = kpos <= qpos[..., None]
-                if vec_idx:
-                    ok &= kpos < (cache_index[:, None, None] + s)
-                else:
-                    ok &= kpos[None, :] < (cache_index + s)
-                if cfg.attention_window is not None:
-                    ok &= kpos > (qpos[..., None] - cfg.attention_window)
-                bias = jnp.where(ok, 0.0, -jnp.inf).astype(jnp.float32)
-                scores = jnp.einsum(
-                    "bqhd,bkhd->bhqk",
-                    q,
-                    _repeat_kv(ck, h // kh),
-                    preferred_element_type=jnp.float32,
-                ) / math.sqrt(hd)
-                scores = scores + (bias[:, None] if vec_idx else bias[None, None])
-                w = jax.nn.softmax(scores, axis=-1)
-                out = jnp.einsum(
-                    "bhqk,bkhd->bqhd", w.astype(v.dtype), _repeat_kv(cv, h // kh)
-                )
+                ok &= kpos[None, :] < (cache_index + s)
+            if cfg.attention_window is not None:
+                ok &= kpos > (qpos[..., None] - cfg.attention_window)
+            bias = jnp.where(ok, 0.0, -jnp.inf).astype(jnp.float32)
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk",
+                q,
+                _repeat_kv(ck, h // kh),
+                preferred_element_type=jnp.float32,
+            ) / math.sqrt(hd)
+            scores = scores + (bias[:, None] if vec_idx else bias[None, None])
+            w = jax.nn.softmax(scores, axis=-1)
+            out = jnp.einsum(
+                "bhqk,bkhd->bqhd", w.astype(v.dtype), _repeat_kv(cv, h // kh)
+            )
     else:
         use_chunked = impl == "chunked" or (impl == "auto" and s > 2048)
         if impl == "pallas":
@@ -436,7 +404,7 @@ def mlp_block(norm_w: Array, params, x: Array, activation: str,
 
 
 # ---------------------------------------------------------------------------
-# MoE (top-k, capacity-bounded, scatter/gather dispatch)
+# MoE (top-k routing, dropless sort-based dispatch, grouped expert FFN)
 # ---------------------------------------------------------------------------
 
 
@@ -456,82 +424,8 @@ def init_moe(key, cfg: ModelConfig, dtype=jnp.float32):
     return p
 
 
-def moe_capacity(tokens_per_group: int, cfg: ModelConfig) -> int:
-    m = cfg.moe
-    c = int(math.ceil(tokens_per_group * m.top_k * m.capacity_factor / m.num_experts))
-    return max(c, 1)
-
-
-def moe_apply(params, x: Array, cfg: ModelConfig):
-    """x: (B, S, D). Returns (y, aux_loss).
-
-    Dispatch via scatter-add into an (E, C, D) per-group buffer (group =
-    batch row), which avoids the O(tokens x E x C) one-hot and maps to
-    all-to-all under expert sharding.
-    """
-    m = cfg.moe
-    b, s, d = x.shape
-    e, k = m.num_experts, m.top_k
-    c = moe_capacity(s, cfg)
-
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), params["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = jax.lax.top_k(probs, k)  # (B, S, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    # position of each (token, choice) within its expert, per group (=batch row)
-    def group_positions(eids):  # (S, k) -> (S, k) position_in_expert
-        flat = eids.reshape(-1)  # (S*k,) in token-major order
-        onehot = jax.nn.one_hot(flat, e, dtype=jnp.int32)  # (S*k, E)
-        pos = jnp.cumsum(onehot, axis=0) - 1  # occurrences before + self
-        return jnp.take_along_axis(pos, flat[:, None], axis=1)[:, 0].reshape(eids.shape)
-
-    pos_in_expert = jax.vmap(group_positions)(expert_ids)  # (B, S, k)
-    keep = pos_in_expert < c
-    slot = expert_ids * c + jnp.minimum(pos_in_expert, c - 1)  # (B, S, k)
-
-    def dispatch_group(xg, slotg, keepg):  # (S,D),(S,k),(S,k)
-        # k separate scatters: avoids materializing the (S*k, D) repeated
-        # token tensor (whose cotangent all-reduced ~1.5 TB/step on
-        # qwen3-235b train_4k; SPerf iteration 2)
-        buf = jnp.zeros((e * c, d), x.dtype)
-        for j in range(k):
-            buf = buf.at[slotg[:, j]].add(xg * keepg[:, j : j + 1].astype(x.dtype))
-        return buf
-
-    buf = jax.vmap(dispatch_group)(x, slot, keep)  # (B, E*C, D)
-    buf = constrain(buf.reshape(b, e, c, d), {0: "batch", 1: "model"})
-
-    # expert FFN: (B, E, C, D) -> (B, E, C, D), contracting per expert
-    if cfg.activation == "swiglu":
-        g = jnp.einsum("becd,edf->becf", buf, params["w_gate"].astype(x.dtype))
-        u = jnp.einsum("becd,edf->becf", buf, params["w_up"].astype(x.dtype))
-        hcurr = jax.nn.silu(g) * u
-    else:
-        u = jnp.einsum("becd,edf->becf", buf, params["w_up"].astype(x.dtype))
-        hcurr = activation_fn(cfg.activation)(u)
-    out = jnp.einsum("becf,efd->becd", hcurr, params["w_down"].astype(x.dtype))
-    out = constrain(out, {0: "batch", 1: "model"})
-    out = out.reshape(b, e * c, d)
-
-    def combine_group(outg, slotg, keepg, gateg):  # (E*C,D),(S,k),(S,k),(S,k)
-        got = outg[slotg.reshape(-1)].reshape(s, k, d)
-        w = (gateg * keepg).astype(x.dtype)
-        return jnp.einsum("skd,sk->sd", got, w)
-
-    y = jax.vmap(combine_group)(out, slot, keep, gate_vals)
-
-    # load-balance aux loss (Switch): E * sum_e f_e * P_e
-    f_e = jnp.mean(
-        jax.nn.one_hot(expert_ids[..., 0], e, dtype=jnp.float32), axis=(0, 1)
-    )
-    p_e = jnp.mean(probs, axis=(0, 1))
-    aux = e * jnp.sum(f_e * p_e) * m.router_aux_weight
-    return y, aux
-
-
 def _moe_route(params, xt: Array, cfg: ModelConfig):
-    """Shared token routing for the dropless + dense-reference paths.
+    """Shared token routing for the dropless path and its dense reference.
 
     xt: (T, D) flattened tokens. Returns (gates (T, k) f32 renormalized
     over the k choices, expert_ids (T, k) int32 over ALL ``num_experts``,
@@ -651,12 +545,11 @@ def moe_dropless(params, x: Array, cfg: ModelConfig, *, impl: str = "auto",
 
     Every routed (token, choice) whose expert is held here is computed -
     no capacity buffer, no token dropping, so the output of a token is
-    independent of which other tokens share its dispatch group (the
-    structural defect behind the old ``jamba_decode`` xfail: the capacity
-    path drops differently at prefill group size vs decode group size 1).
-    The router scores all ``num_experts``; choices of experts held
-    elsewhere (``MoEConfig.held`` < ``num_experts``) sort after the held
-    ones and add nothing, so the shares of a layer sum to the whole layer.
+    independent of which other tokens are routed with it (a prefill and a
+    one-token decode step agree). The router scores all ``num_experts``;
+    choices of experts held elsewhere (``MoEConfig.held`` < ``num_experts``)
+    sort after the held ones and add nothing, so the shares of a layer sum
+    to the whole layer.
 
     x: (B, S, D) -> (y, aux, rows), ``rows`` (held,) int32 the routed rows
     of each held expert. Stable-argsort the (T*k) flat expert ids, gather

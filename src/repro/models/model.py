@@ -3,7 +3,7 @@
 Architecture-generic: the per-layer ``signature`` (block kind A/M, MoE flag,
 MLP presence) is derived from the config; layers are grouped into the
 smallest repeating period so ``jax.lax.scan`` keeps compile time O(period),
-not O(depth) - essential for 94-96 layer models on the dry-run host.
+not O(depth).
 
 KV/SSM caches are stacked per slot (leading dim = repeats) and carried
 through the layer loop: each layer writes its new K/V rows (or SSM state)
@@ -13,17 +13,15 @@ the new positions and never slices out or restacks a layer's cache.
 from __future__ import annotations
 
 import math
-from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distribution.context import constrain
 from repro.models import layers as L
 from repro.models import ssm as S
-from repro.models.frontends import FRONTEND_DIMS, init_frontend, frontend_apply
+from repro.models.frontends import init_frontend, frontend_apply
 
 Array = jax.Array
 
@@ -129,17 +127,8 @@ def block_apply(
     x = x + out
     if has_mlp:
         if is_moe:
-            from repro.distribution.context import moe_a2a_enabled
-            from repro.models.moe_a2a import a2a_applicable, moe_apply_a2a
-
             h2 = L.rms_norm(x, p["norm2"], cfg.norm_eps)
-            rows = jnp.zeros((cfg.moe.held,), jnp.int32)
-            if moe_a2a_enabled() and a2a_applicable(cfg):
-                y, aux = moe_apply_a2a(p["moe"], h2, cfg)
-            elif cfg.moe.dispatch == "dropless":
-                y, aux, rows = L.moe_dropless(p["moe"], h2, cfg)
-            else:
-                y, aux = L.moe_apply(p["moe"], h2, cfg)
+            y, aux, rows = L.moe_dropless(p["moe"], h2, cfg)
             stats = {"aux": aux, "moe_rows": rows}
             x = x + y
         elif impl == "pallas_stage":
@@ -237,7 +226,6 @@ def forward(
     frontend_feats: Optional[Array] = None,
     impl: str = "auto",
     remat: bool = False,
-    unroll: bool = False,
     compute_dtype=jnp.bfloat16,
     last: Optional[Array] = None,
 ):
@@ -253,7 +241,6 @@ def forward(
     b, s_tok = tokens.shape
 
     x = params["embed"].astype(compute_dtype)[tokens]
-    x = constrain(x, {0: "batch"})
     if frontend_feats is not None:
         fe = frontend_apply(params["frontend"], frontend_feats.astype(compute_dtype))
         x = jnp.concatenate([fe, x], axis=1)
@@ -280,16 +267,11 @@ def forward(
                     positions=positions, cache=None if cc is None else cc[si],
                     cache_index=cache_index, layer=r, impl=impl,
                 )
-                xact = constrain(xact, {0: "batch"})
                 new_caches.append(nc)
                 aux = aux + st["aux"]
             return xact, aux, None if cc is None else tuple(new_caches)
 
         if remat:
-            # NOTE: save_only_these_names("moe_a2a") was measured (SPerf
-            # pair 1, iter 5b): it cuts the exchange 3786->... but pins
-            # ~2.3 TB/dev of buffers - recompute is the right side of the
-            # trade at 16 GiB/chip, so nothing is saved.
             f = jax.checkpoint(inner, policy=jax.checkpoint_policies.nothing_saveable)
         else:
             f = inner
@@ -303,13 +285,7 @@ def forward(
     # is the loop's own work (slicing each layer's weights out of the
     # stacks), read by the benchmark; the caches ride in the carry
     with jax.named_scope("model.layers"):
-        if unroll:
-            # python-loop unroll: true per-layer HLO (exact flop/collective
-            # accounting in the dry-run; scan counts the body only once)
-            for r in range(repeats):
-                sp = jax.tree.map(lambda a: a[r], params["slots"])
-                carry, _ = body(carry, (sp, r))
-        elif caches is None:
+        if caches is None:
             carry, _ = jax.lax.scan(lambda c, sp: body(c, (sp, None)), carry,
                                     params["slots"])
         else:
@@ -324,7 +300,6 @@ def forward(
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     ).astype(compute_dtype)
     logits = jnp.einsum("bsd,dv->bsv", x, head)
-    logits = constrain(logits, {0: "batch", 2: "model"})
     if last is not None:
         logits = logits[:, 0]
     return logits, new_caches, aux
@@ -337,7 +312,7 @@ def forward(
 
 def softmax_xent(logits: Array, labels: Array, mask: Optional[Array] = None):
     """logits: (B,S,V) ; labels: (B,S) int32; mask: (B,S) 1=count."""
-    logits = constrain(logits.astype(jnp.float32), {0: "batch", 2: "model"})
+    logits = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     nll = logz - gold
@@ -347,14 +322,14 @@ def softmax_xent(logits: Array, labels: Array, mask: Optional[Array] = None):
     return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
-def loss_fn(params, batch, cfg: ModelConfig, *, impl="auto", remat=True, unroll=False,
+def loss_fn(params, batch, cfg: ModelConfig, *, impl="auto", remat=True,
             compute_dtype=jnp.bfloat16):
     tokens = batch["tokens"]
     labels = batch["labels"]
     frontend = batch.get("frontend")
     logits, _, aux = forward(
         params, tokens, cfg, frontend_feats=frontend, impl=impl, remat=remat,
-        unroll=unroll, compute_dtype=compute_dtype,
+        compute_dtype=compute_dtype,
     )
     if frontend is not None:
         # loss only over the text region (frontend positions are prefix)
@@ -364,37 +339,28 @@ def loss_fn(params, batch, cfg: ModelConfig, *, impl="auto", remat=True, unroll=
     return loss + aux, (loss, aux)
 
 
-def make_train_step(cfg: ModelConfig, optimizer, *, impl="auto", remat=True, unroll=False,
-                    compute_copy_dtype=None, param_shardings_tree=None):
+def make_train_step(cfg: ModelConfig, optimizer, *, impl="auto", remat=True,
+                    compute_copy_dtype=None):
     """compute_copy_dtype: when set (e.g. jnp.bfloat16), matrix params are
     cast to it ONCE per step before the forward pass, so FSDP all-gathers
     and all weight reads move half the bytes; the f32 master copy and the
-    optimizer update stay full precision (classic mixed precision).
-
-    param_shardings_tree: when given, the casted copy is PINNED to the same
-    sharding as the master param - without this, GSPMD hoists the FSDP
-    all-gather ABOVE the convert and gathers f32 anyway (measured, SPerf
-    iteration 3)."""
+    optimizer update stay full precision (classic mixed precision)."""
 
     def cast_tree(p):
         if compute_copy_dtype is None:
             return p
 
-        def one(a, sh=None):
+        def one(a):
             if a.dtype == jnp.float32 and a.ndim >= 2:
                 a = a.astype(compute_copy_dtype)
-                if sh is not None:
-                    a = jax.lax.with_sharding_constraint(a, sh)
             return a
 
-        if param_shardings_tree is None:
-            return jax.tree.map(one, p)
-        return jax.tree.map(one, p, param_shardings_tree)
+        return jax.tree.map(one, p)
 
     def train_step(params, opt_state, batch):
         if compute_copy_dtype is None:
             (total, (loss, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, batch, cfg, impl=impl, remat=remat, unroll=unroll
+                params, batch, cfg, impl=impl, remat=remat
             )
         else:
             # differentiate wrt the LOW-PRECISION copy: the gradient
@@ -402,8 +368,7 @@ def make_train_step(cfg: ModelConfig, optimizer, *, impl="auto", remat=True, unr
             # compute_copy_dtype bytes, and the f32 master update follows.
             params_c = cast_tree(params)
             (total, (loss, aux)), grads_c = jax.value_and_grad(
-                lambda p: loss_fn(p, batch, cfg, impl=impl, remat=remat,
-                                  unroll=unroll),
+                lambda p: loss_fn(p, batch, cfg, impl=impl, remat=remat),
                 has_aux=True,
             )(params_c)
             grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads_c, params)
@@ -417,12 +382,12 @@ def make_train_step(cfg: ModelConfig, optimizer, *, impl="auto", remat=True, unr
     return train_step
 
 
-def make_prefill_step(cfg: ModelConfig, *, impl="auto", unroll=False,
+def make_prefill_step(cfg: ModelConfig, *, impl="auto",
                       compute_dtype=jnp.bfloat16):
     def prefill(params, tokens, caches, frontend_feats=None):
         logits, new_caches, _ = forward(
             params, tokens, cfg, caches=caches, cache_index=jnp.zeros((), jnp.int32),
-            frontend_feats=frontend_feats, impl=impl, remat=False, unroll=unroll,
+            frontend_feats=frontend_feats, impl=impl, remat=False,
             compute_dtype=compute_dtype,
         )
         return logits[:, -1], new_caches
@@ -430,14 +395,14 @@ def make_prefill_step(cfg: ModelConfig, *, impl="auto", unroll=False,
     return prefill
 
 
-def make_decode_step(cfg: ModelConfig, *, impl="auto", unroll=False,
+def make_decode_step(cfg: ModelConfig, *, impl="auto",
                      compute_dtype=jnp.bfloat16):
     def decode(params, tokens, caches, cache_index):
         """tokens: (B, 1); cache_index: int32 tokens already seen - a scalar
         (lockstep batch) or a (B,) vector (per-slot counts, serving engine)."""
         logits, new_caches, _ = forward(
             params, tokens, cfg, caches=caches, cache_index=cache_index,
-            impl=impl, remat=False, unroll=unroll, compute_dtype=compute_dtype,
+            impl=impl, remat=False, compute_dtype=compute_dtype,
         )
         return logits[:, -1], new_caches
 

@@ -29,7 +29,7 @@ class ServeConfig:
     """
 
     arch: str = "qwen2_5_3b"      # repro.configs module name
-    reduced: bool = True          # .reduced() dry-run arch (CPU-sized)
+    reduced: bool = True          # .reduced() arch (CPU-sized)
     num_layers: Optional[int] = None  # override depth (benchmarks)
     num_slots: int = 8
     arrival_slots: int = 4

@@ -20,13 +20,12 @@ Both runners' caches put the slot axis at -4 and the position axis at
 ``(S, max_len, B, C, KH, hd)`` rings on a stage mesh); the scratch cache
 and the row writes rely on those two axes alone.
 
-Both backends restrict to attention-only architectures (MoE allowed
-under DROPLESS dispatch): padded batched prefill relies on causal
-masking to keep pad garbage out of valid rows, which holds for KV caches
-but NOT for SSM recurrent state (pad tokens would pollute it) or
-capacity-bounded MoE routing (pad tokens would steal expert capacity
-from real rows - dropless dispatch computes every routed token, so each
-row's output is independent of its dispatch-group neighbours).
+Both backends restrict to attention-only architectures, MoE included:
+padded batched prefill relies on causal masking to keep pad garbage out
+of valid rows, which holds for KV caches but NOT for SSM recurrent state
+(pad tokens would pollute it). Dropless MoE dispatch computes every
+routed token, so each row's output is independent of the rows routed
+with it.
 """
 from __future__ import annotations
 
@@ -45,9 +44,9 @@ def check_servable(cfg: ModelConfig) -> None:
 
     Attention configs serve (any layer-group period - the single-device
     runner scans slots natively and the pipeline runner dispatches a
-    static block-kind schedule); MoE layers serve under DROPLESS dispatch
-    only. SSM/hybrid stay rejected: padded batched prefill relies on
-    causal masking, which protects KV attention but not recurrent state.
+    static block-kind schedule), MoE layers included. SSM/hybrid stay
+    rejected: padded batched prefill relies on causal masking, which
+    protects KV attention but not recurrent state.
     """
     sig = M.signature(cfg)
     if any(kind != "A" for kind, _, _ in sig):
@@ -55,11 +54,6 @@ def check_servable(cfg: ModelConfig) -> None:
             "serving engine: SSM/hybrid archs are unservable - padded "
             "batched prefill is masked out of KV attention but would "
             "pollute the recurrent scan state")
-    if any(is_moe for _, is_moe, _ in sig) and cfg.moe.dispatch != "dropless":
-        raise ValueError(
-            "serving engine: capacity-dropping MoE is unservable (padded "
-            "prefill rows steal expert capacity from real rows); set "
-            "moe.dispatch='dropless'")
 
 
 class SingleDeviceRunner:

@@ -188,7 +188,7 @@ class ServingService:
                                              compute_dtype=dtype)
         else:
             from repro.core.pipeline import PipelineConfig
-            from repro.launch.mesh import make_stage_mesh
+            from repro.distribution.mesh import make_stage_mesh
 
             if mesh is None:
                 mesh = make_stage_mesh(len(cfg.boundaries))

@@ -18,8 +18,8 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "jamba_decode: tracks jamba greedy-decode vs teacher-forced-forward "
-        "agreement. RETIRED as an xfail: dropless MoE dispatch (the "
-        "default) computes every routed token, so a token's output no "
+        "agreement. RETIRED as an xfail: dropless MoE dispatch "
+        "computes every routed token, so a token's output no "
         "longer depends on its dispatch-group size and decode matches the "
         "forward - the test must now PASS (see test_models_smoke.py)",
     )
@@ -28,8 +28,8 @@ def pytest_configure(config):
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 420) -> str:
     """Run a python snippet in a subprocess with a forced host device count.
 
-    Tests in THIS process keep the default single device (per the dry-run
-    contract); multi-device behaviour is exercised in clean subprocesses.
+    Tests in THIS process keep the default single device; multi-device
+    behaviour is exercised in clean subprocesses.
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"

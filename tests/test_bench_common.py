@@ -75,12 +75,12 @@ def test_only_selection_exact_or_prefix():
 
     assert select(ALL, "fig10") == ["fig10_leakage_attack"]
     assert select(ALL, "pipeline") == ["pipeline"]
-    assert select(ALL, "moe_dispatch,zoo_plan_scoring") == [
-        "moe_dispatch", "zoo_plan_scoring"]
+    assert select(ALL, "zoo_plan_scoring,serving") == [
+        "serving", "zoo_plan_scoring"]
     # list-order output regardless of spec order; duplicates collapse
     assert select(ALL, "serving,pipeline,serving") == ["pipeline", "serving"]
     with pytest.raises(SystemExit):
         select(ALL, "fig1")  # prefix of fig10_... but not an explicit one
     with pytest.raises(SystemExit):
         select(ALL, "nope")
-    assert "moe_dispatch" in ALL and "zoo_plan_scoring" in ALL
+    assert "serving" in ALL and "zoo_plan_scoring" in ALL

@@ -98,37 +98,42 @@ print('UNEVEN_OK')
     assert "UNEVEN_OK" in out
 
 
-def test_sharded_train_step_runs(subproc):
-    """pjit train step on a 2x2 host mesh with real (reduced) params."""
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "qwen3-moe-30b-a3b"])
+def test_sharded_train_step_runs(subproc, arch):
+    """jit train step on a 2x2 host mesh with real (reduced) params, from
+    the parameter, optimizer-state and batch shardings alone: its loss is
+    the unsharded step's."""
     out = subproc(
-        """
+        f"""
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.models import init_params, make_train_step
-from repro.distribution.sharding import param_shardings, batch_axes
-from repro.distribution.context import activation_sharding
-from repro.launch.mesh import make_host_mesh
+from repro.distribution.sharding import param_shardings
+from repro.distribution.mesh import make_host_mesh
 from repro.optim import adamw
 from repro.data import synthetic_batch
 
-cfg = get_config('qwen3-moe-30b-a3b').reduced()
+cfg = get_config({arch!r}).reduced()
 mesh = make_host_mesh(2, 2)
 params = init_params(jax.random.PRNGKey(0), cfg)
-psh = param_shardings(jax.eval_shape(lambda: params), cfg, mesh)
-params = jax.tree.map(lambda a, s: jax.device_put(a, s), params, psh)
 opt = adamw(1e-3)
 ostate = opt.init(params)
-osh = param_shardings(jax.eval_shape(lambda: ostate), cfg, mesh)
 batch = synthetic_batch(cfg, 4, 32)
-bsh = {k: NamedSharding(mesh, P('data', *([None]*(v.ndim-1)))) for k, v in batch.items()}
-batch = jax.tree.map(lambda a, s: jax.device_put(a, s), batch, bsh)
+_, _, m_ref = jax.jit(make_train_step(cfg, opt))(params, ostate, batch)
+
+psh = param_shardings(jax.eval_shape(lambda: params), cfg, mesh)
+osh = param_shardings(jax.eval_shape(lambda: ostate), cfg, mesh)
+bsh = {{k: NamedSharding(mesh, P('data', *([None]*(v.ndim-1))))
+        for k, v in batch.items()}}
+put = lambda t, sh: jax.tree.map(jax.device_put, t, sh)
 step = jax.jit(make_train_step(cfg, opt), in_shardings=(psh, osh, bsh),
                out_shardings=(psh, osh, None))
-with activation_sharding(mesh, ('data',)):
-    p2, o2, m = step(params, ostate, batch)
-assert bool(jnp.isfinite(m['loss'])), m
-print('SHARDED_TRAIN_OK', float(m['loss']))
+p2, o2, m = step(put(params, psh), put(ostate, osh), put(batch, bsh))
+loss, ref = float(m['loss']), float(m_ref['loss'])
+assert jnp.isfinite(loss), m
+assert abs(loss - ref) <= 1e-3 * abs(ref), (loss, ref)
+print('SHARDED_TRAIN_OK', loss, ref)
 """,
         n_devices=4,
     )

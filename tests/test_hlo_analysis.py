@@ -1,10 +1,8 @@
-"""HLO collective parser + roofline unit tests."""
+"""HLO collective parser unit tests."""
 import numpy as np
 
 from repro.launch.hlo_analysis import (
-    CollectiveStats,
     parse_collectives,
-    roofline_from,
     split_computations,
     loop_multipliers,
 )
@@ -54,16 +52,6 @@ def test_loop_multipliers_trip_fallback():
     hlo = HLO.replace(', backend_config={"known_trip_count":{"n":"5"}}', "")
     st = parse_collectives(hlo, loop_aware=True)
     assert st.counts["all-gather"] == 5  # constant(5) in the condition
-
-
-def test_roofline_dominant():
-    coll = CollectiveStats(
-        result_bytes={"all-reduce": 10}, wire_bytes={"all-reduce": 1e9}, counts={}
-    )
-    r = roofline_from({"flops": 1e12, "bytes accessed": 1e9}, coll, 5e11)
-    assert r.dominant == "collective"
-    assert abs(r.compute_s - 1e12 / 197e12) < 1e-9
-    assert r.useful_ratio == 0.5
 
 
 def test_pipeline_collective_counts_synthetic():

@@ -78,33 +78,15 @@ def _decode_vs_forward_err(cfg) -> float:
 @pytest.mark.parametrize("arch", [
     "qwen2.5-3b",
     "mamba2-370m",
-    # xfail RETIRED: under dropless MoE dispatch (the default) every routed
-    # token is computed, so a token's output is independent of its
-    # dispatch-group size and teacher-forced forward (8-token groups)
-    # agrees with single-token decode. The old capacity path (ceil(T*k*cf/E)
-    # buffer) dropped tokens group-size-dependently - that structural
-    # disagreement is what the xfail tracked.
+    # xfail RETIRED: dropless MoE dispatch computes every routed token, so
+    # a token's output is independent of its dispatch-group size and
+    # teacher-forced forward (8-token groups) agrees with single-token
+    # decode.
     pytest.param("jamba-v0.1-52b", marks=[pytest.mark.jamba_decode]),
 ])
 def test_decode_matches_forward(arch):
     """Greedy decode logits must match teacher-forced forward logits."""
     err = _decode_vs_forward_err(get_config(arch).reduced())
-    assert err < 2e-2, err
-
-
-def test_jamba_decode_matches_forward_capacity_neutralized():
-    """The hybrid SSM/attention cache handoff is exact even on the legacy
-    CAPACITY dispatch path, once its dropping is neutralized
-    (capacity_factor >> 1 admits every token at both group sizes). This
-    keeps the retired jamba_decode xfail's diagnosis pinned: the old
-    decode drift came from capacity-dropping context dependence, not the
-    state handoff."""
-    from dataclasses import replace
-
-    cfg = get_config("jamba-v0.1-52b").reduced()
-    cfg = replace(cfg, moe=replace(cfg.moe, dispatch="capacity",
-                                   capacity_factor=64.0))
-    err = _decode_vs_forward_err(cfg)
     assert err < 2e-2, err
 
 

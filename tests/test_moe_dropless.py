@@ -3,12 +3,10 @@
 ``layers.moe_apply_dropless`` (stable-sort grouping + block-padded
 grouped matmul) must be BITWISE-equal to the dense per-expert reference
 ``layers.moe_apply_dense`` - same routing (shared ``_moe_route``), same
-per-row arithmetic, merely regrouped. Bitwise parity is what retired the
-``jamba_decode`` xfail: the capacity path drops different (token, choice)
-pairs at different group sizes, so decode-time groups disagreed with
-prefill; the dropless path computes every routed pair, so outputs are
-independent of grouping - pinned here directly by the block-size and
-decode-slice invariance tests.
+per-row arithmetic, merely regrouped. The dropless path computes every
+routed pair, so outputs are independent of grouping and decode agrees
+with prefill (what retired the ``jamba_decode`` xfail) - pinned here
+directly by the block-size and decode-slice invariance tests.
 """
 import jax
 import jax.numpy as jnp
@@ -56,9 +54,9 @@ def test_dropless_pallas_matches_dense(arch):
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_dropless_decode_slice_bitwise(arch):
-    """Group-size independence - the property the capacity path lacks:
-    dispatching a single decode position alone must produce BITWISE the
-    same rows as dispatching it inside the full prefill batch."""
+    """Group-size independence: dispatching a single decode position
+    alone must produce BITWISE the same rows as dispatching it inside the
+    full prefill batch."""
     cfg, params, x = _setup(arch)
     y_full, _ = L.moe_apply_dropless(params, x, cfg, impl="reference",
                                      block_size=32)

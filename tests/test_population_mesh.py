@@ -32,7 +32,7 @@ from repro.core.scenario import (
     stack_scenarios,
     train_population,
 )
-from repro.launch.mesh import make_population_mesh
+from repro.distribution.mesh import make_population_mesh
 
 
 @pytest.fixture(scope="module")
@@ -83,7 +83,7 @@ from repro.core.agents.sac import SACConfig
 from repro.core.env import MHSLEnv
 from repro.core.profiles import resnet101_profile
 from repro.core.scenario import scenario_grid, stack_scenarios, train_population
-from repro.launch.mesh import make_population_mesh
+from repro.distribution.mesh import make_population_mesh
 
 env = MHSLEnv(profile=resnet101_profile(batch=1))
 cfg = SACConfig()
@@ -117,7 +117,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
 from repro.models import init_params
 from repro.core.pipeline import PipelineConfig, make_stage_mesh, pipeline_step_fn
-from repro.launch.mesh import make_stage_env_mesh
+from repro.distribution.mesh import make_stage_env_mesh
 from repro.distribution.sharding import (
     microbatch_sharding, population_axes, stage_sharding,
 )
@@ -166,7 +166,7 @@ from repro.core.agents.sac import SACConfig
 from repro.core.env import MHSLEnv
 from repro.core.profiles import resnet101_profile
 from repro.core.scenario import scenario_grid, stack_scenarios, train_population
-from repro.launch.mesh import make_stage_env_mesh
+from repro.distribution.mesh import make_stage_env_mesh
 
 env = MHSLEnv(profile=resnet101_profile(batch=1))
 cfg = SACConfig()

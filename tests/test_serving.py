@@ -75,20 +75,16 @@ def test_vector_cache_index_bitwise_matches_scalar():
 
 
 def test_check_servable_moe_and_ssm_gates():
-    """PR 9 servability matrix: SSM/hybrid stay rejected (padded prefill
-    pollutes recurrent state), MoE serves under dropless dispatch ONLY
-    (capacity buffers let padding rows steal expert capacity)."""
-    from dataclasses import replace
+    """Servability matrix: SSM/hybrid stay rejected (padded prefill
+    pollutes recurrent state), MoE serves (dropless dispatch computes every
+    routed token, padding rows included)."""
     from repro.configs import get_config
 
     with pytest.raises(ValueError, match="SSM/hybrid"):
         check_servable(get_config("jamba-v0.1-52b").reduced())
     with pytest.raises(ValueError, match="SSM/hybrid"):
         check_servable(get_config("mamba2-370m").reduced())
-    moe = get_config("qwen3-moe-30b-a3b").reduced()
-    check_servable(moe)  # dropless (the default): servable
-    with pytest.raises(ValueError, match="dropless"):
-        check_servable(replace(moe, moe=replace(moe.moe, dispatch="capacity")))
+    check_servable(get_config("qwen3-moe-30b-a3b").reduced())  # servable
 
 
 # ---------------------------------------------------------------------------
@@ -430,7 +426,7 @@ def test_pipeline_serve_moe_prefill_bitwise(subproc):
     dispatch makes every row per-row independent, so the padded pipeline
     prefill must be BITWISE the plain forward pass - for the pure-MoE
     period-1 config AND a mixed MoE/dense period-2 stack - and a decode
-    tick must produce finite logits. Capacity dispatch is refused."""
+    tick must produce finite logits."""
     out = subproc(
         """
 import jax, jax.numpy as jnp, numpy as np
@@ -463,13 +459,6 @@ for name, cfg in cases.items():
     dlg, caches = jax.jit(decode)(params, tok, caches,
                                   jnp.full((b,), p, jnp.int32))
     assert bool(jnp.all(jnp.isfinite(dlg))), name
-try:
-    pipeline_serve_fns(
-        replace(cases['period1'],
-                moe=replace(base.moe, dispatch='capacity')), mesh, (1, 2))
-    raise SystemExit('capacity MoE dispatch not refused')
-except ValueError as e:
-    assert 'dropless' in str(e)
 print('MOE_SERVE_OK', len(cases))
 """,
         n_devices=2)
